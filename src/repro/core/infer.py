@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sync import CommMeter, LocalReducer, MeshReducer, Reducer
-from repro.core.types import LDAConfig, MiniBatch
+from repro.core.types import HIGHEST, LDAConfig, MiniBatch
 
 
 @dataclasses.dataclass
@@ -98,6 +98,24 @@ def _init_messages(key: jax.Array, batch: MiniBatch, cfg: LDAConfig,
     return u / norm
 
 
+def _fold_in_carry(cfg: LDAConfig, impl: str, kl: int, n_docs: int,
+                   local_topics: bool) -> str:
+    """The fold-in formulation: 'xla' for the jnp impl, for a topic-sharded
+    phi (the kernel normalizes over the whole topic axis in-kernel; the
+    bypass is logged) and for shapes the carry kernel cannot take; else
+    the full-K ('dense_layout') or K-blocked carry kernel
+    (`core.sweep_dispatch.resolve_fold_in`, the same VMEM-fit dispatch as
+    training — DESIGN.md §13)."""
+    from repro.core.sweep_dispatch import note_bypass, resolve_fold_in
+    if impl != "pallas":
+        return "xla"
+    if not local_topics:
+        note_bypass("fold_in", dict(D=n_docs, Kl=kl))
+        return "xla"
+    return resolve_fold_in(kl, n_docs, cfg.sweep_policy,
+                           cfg.vmem_budget_bytes)
+
+
 def fold_in_tokens(key: jax.Array, batch: MiniBatch, phi_norm_wk: jnp.ndarray,
                    cfg: LDAConfig, iters: int = 30,
                    residual_tol: float = 0.0,
@@ -126,7 +144,9 @@ def fold_in_tokens(key: jax.Array, batch: MiniBatch, phi_norm_wk: jnp.ndarray,
     phi_tok = jnp.take(phi_norm_wk, layout.word_ids, axis=0)    # [T, Kl], once
     theta0 = (c * mu_t).reshape(D, L, Kl).sum(axis=1)           # [D, Kl]
 
-    use_pallas = impl == "pallas" and isinstance(model_reducer, LocalReducer)
+    carry = _fold_in_carry(cfg, impl, Kl, D,
+                           isinstance(model_reducer, LocalReducer))
+    use_pallas = carry != "xla"
     if use_pallas:
         from repro.kernels.power_sweep.ops import power_sweep_carry
         # constant phi row table for the carry megakernel, built once per
@@ -141,16 +161,7 @@ def fold_in_tokens(key: jax.Array, batch: MiniBatch, phi_norm_wk: jnp.ndarray,
             [phi_norm_wk, jnp.zeros((1, Kl), phi_norm_wk.dtype)], axis=0)
         mask_dummy = jnp.zeros((1, Kl), jnp.float32)
         pt_zero = jnp.zeros((Kl,), jnp.float32)
-        # same VMEM-fit dispatch as training (DESIGN.md §13), with the
-        # serving row table being the whole vocabulary: the full-K carry
-        # kernel while it fits, the K-blocked two-pass kernel beyond, or
-        # pinned by an explicit cfg.sweep_policy == 'kblocked'
-        from repro.core.sweep_dispatch import carry_vmem_fit
-        serve_kblocked = (
-            cfg.sweep_policy == "kblocked"
-            or (cfg.sweep_policy == "auto"
-                and not carry_vmem_fit(Kl, w_rows, D,
-                                       cfg.vmem_budget_bytes)))
+        serve_kblocked = carry == "kblocked"
 
     def active_docs(r_doc, r_prev):
         # geometric-tail bound on the theta movement still to come: with
@@ -366,8 +377,9 @@ def make_slab_step(cfg: LDAConfig, *, slots: int, slot_len: int,
         reducer: Reducer = LocalReducer(meter=meter, sync_dtype=sync_dtype)
     else:
         reducer = MeshReducer("model", meter=meter, sync_dtype=sync_dtype)
-    impl_r = cfg.impl if impl is None else impl
-    use_pallas = impl_r == "pallas" and topic_shards == 1
+    carry = _fold_in_carry(cfg, cfg.impl if impl is None else impl, Kl, B,
+                           topic_shards == 1)
+    use_pallas = carry != "xla"
     doc_ids = jnp.repeat(jnp.arange(B, dtype=jnp.int32), L)       # [B*L]
     tol = float(residual_tol)
 
@@ -434,17 +446,13 @@ def make_slab_step(cfg: LDAConfig, *, slots: int, slot_len: int,
         wid_t = wid.reshape(B * L)
         phi_tok = jnp.take(phi_norm, wid_t, axis=0)                # [T, Kl]
         if use_pallas:
-            from repro.core.sweep_dispatch import carry_vmem_fit
             from repro.kernels.power_sweep.ops import power_sweep_carry
             w_rows = phi_norm.shape[0]
             phi_rows = jnp.concatenate(
                 [phi_norm, jnp.zeros((1, Kl), phi_norm.dtype)], axis=0)
             mask_dummy = jnp.zeros((1, Kl), jnp.float32)
             pt_zero = jnp.zeros((Kl,), jnp.float32)
-            kblocked = (cfg.sweep_policy == "kblocked"
-                        or (cfg.sweep_policy == "auto"
-                            and not carry_vmem_fit(Kl, w_rows, B,
-                                                   cfg.vmem_budget_bytes)))
+            kblocked = carry == "kblocked"
         for _ in range(sweeps_per_step):
             act_d = active_slots(r_doc, r_prev, it, live, tok_d)   # [B]
             act_tok = act_d[doc_ids]                               # [T]
@@ -543,12 +551,14 @@ def fold_in_dense_reference(key: jax.Array, batch: MiniBatch,
     c = batch.counts[..., None]
 
     def body(mu, _):
-        theta = jnp.einsum("dl,dlk->dk", batch.counts, mu)
+        theta = jnp.einsum("dl,dlk->dk", batch.counts, mu,
+                           precision=HIGHEST)
         th = theta[:, None, :] - c * mu + cfg.alpha
         unnorm = th * phi_tok
         mu = unnorm / jnp.maximum(jnp.sum(unnorm, -1, keepdims=True), 1e-30)
         return mu, None
 
     mu, _ = jax.lax.scan(body, mu, None, length=iters)
-    theta = jnp.einsum("dl,dlk->dk", batch.counts, mu) + cfg.alpha
+    theta = jnp.einsum("dl,dlk->dk", batch.counts, mu,
+                       precision=HIGHEST) + cfg.alpha
     return theta / jnp.sum(theta, -1, keepdims=True)

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import infer
-from repro.core.types import LDAConfig, MiniBatch
+from repro.core.types import HIGHEST, LDAConfig, MiniBatch
 
 
 def normalize_phi(phi_acc_wk: jnp.ndarray, beta: float,
@@ -55,7 +55,7 @@ def predictive_perplexity(theta: jnp.ndarray, phi_norm_wk: jnp.ndarray,
                           test: MiniBatch) -> jnp.ndarray:
     """Eq. (20) on the held-out split."""
     phi_tok = jnp.take(phi_norm_wk, test.word_ids, axis=0)       # [D, L, K]
-    p = jnp.einsum("dk,dlk->dl", theta, phi_tok)
+    p = jnp.einsum("dk,dlk->dl", theta, phi_tok, precision=HIGHEST)
     logp = jnp.where(test.counts > 0, jnp.log(jnp.maximum(p, 1e-30)), 0.0)
     n = jnp.maximum(jnp.sum(test.counts), 1.0)
     return jnp.exp(-jnp.sum(test.counts * logp) / n)

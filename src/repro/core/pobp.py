@@ -49,9 +49,10 @@ from repro.core import power as pw
 from repro.core import quantize
 from repro.core.residuals import (mean_residual, packed_rw_delta,
                                   token_scatter_wk)
-from repro.core.sweep_dispatch import resolve_sweep_policy
+from repro.core.sweep_dispatch import note_bypass, resolve_sweep_policy
 from repro.core.sync import CommMeter, LocalReducer, MeshReducer, Reducer
-from repro.core.types import LDAConfig, LDATrainState, MiniBatch, TokenLayout
+from repro.core.types import (HIGHEST, LDAConfig, LDATrainState, MiniBatch,
+                              TokenLayout)
 
 
 # --------------------------------------------------------------------------
@@ -82,7 +83,8 @@ def dense_sweep(
     """
     W = cfg.vocab_size
     wb = W * cfg.beta if wbeta is None else wbeta
-    theta = jnp.einsum("dl,dlk->dk", batch.counts, mu)           # Eq. (2), local topics
+    theta = jnp.einsum("dl,dlk->dk", batch.counts, mu,          # Eq. (2),
+                       precision=HIGHEST)                     # local topics
     c = batch.counts[..., None]
     self_c = c * mu
     th = theta[:, None, :] - self_c + cfg.alpha
@@ -217,7 +219,7 @@ def _apply_token_update(layout: TokenLayout, mu_t, theta, k_tok, mu_sel,
     counts2 = layout.counts.reshape(layout.num_docs, layout.max_len)
     theta_new = theta + jnp.einsum(
         "dl,dlk->dk", counts2,
-        delta.reshape(layout.num_docs, layout.max_len, K))
+        delta.reshape(layout.num_docs, layout.max_len, K), precision=HIGHEST)
     return mu_t_new, theta_new, d_mu
 
 
@@ -273,8 +275,9 @@ def _selective_sweep_packed(
         onehot_p = (p_tok[:, None] ==
                     jnp.arange(P, dtype=p_tok.dtype)[None, :]).astype(mu_t.dtype)
         dims = (((0,), (0,)), ((), ()))
-        delta_phi_packed = jax.lax.dot_general(onehot_p, cd, dims)
-        r_packed = jax.lax.dot_general(onehot_p, rv, dims)
+        delta_phi_packed = jax.lax.dot_general(onehot_p, cd, dims,
+                                               precision=HIGHEST)
+        r_packed = jax.lax.dot_general(onehot_p, rv, dims, precision=HIGHEST)
     else:
         # p_tok == P for non-power tokens -> dropped by the bounds check
         delta_phi_packed = jnp.zeros((P, Pk), mu_t.dtype).at[p_tok].add(
@@ -340,7 +343,7 @@ def _selective_sweep_dense_layout(
     mass = jnp.sum(jnp.where(selp, mu3, 0.0), -1, keepdims=True)
     denom = jnp.maximum(jnp.sum(u, -1, keepdims=True), 1e-30)
     mu_new = jnp.where(selp, u * (mass / denom), mu3)
-    theta_new = jnp.einsum("dl,dlk->dk", counts2, mu_new)
+    theta_new = jnp.einsum("dl,dlk->dk", counts2, mu_new, precision=HIGHEST)
     cd = c3 * (mu_new - mu3)
     zc = jax.lax.complex(cd, jnp.abs(cd)).reshape(layout.num_slots, Kl)
     rows = jnp.zeros((P + 1, Kl), jnp.complex64).at[
@@ -432,6 +435,8 @@ def selective_sweep_tokens_pallas(
 ):
     """Fused-kernel selective sweep, policy-dispatched like the jnp path.
 
+    ``xla`` (auto's resolution where the carry kernels' one-hot work or
+    VMEM footprint rules them out) runs the jnp dense-layout formulation.
     ``dense_layout`` (the 'auto' resolution on the pallas backend while
     the full-K carry fits VMEM) runs the carry-resident
     `power_sweep_carry` megakernel — one HBM read + one write of the
@@ -449,6 +454,11 @@ def selective_sweep_tokens_pallas(
     policy = resolve_sweep_policy(cfg, layout.num_slots, mu_t.shape[1],
                                   Pk, P, impl="pallas",
                                   n_docs=theta.shape[0])
+    if policy == "xla":
+        # the dispatch printed why (core/sweep_dispatch.DISPATCH_LOG)
+        return _selective_sweep_dense_layout(
+            layout, mu_t, theta, phi_eff_wk, phi_tot, sel_w, sel_k, cfg,
+            wbeta=wbeta)
     if policy in ("dense_layout", "kblocked"):
         return _selective_sweep_carry_pallas(
             layout, mu_t, theta, phi_eff_wk, phi_tot, sel_w, sel_k, cfg,
@@ -476,6 +486,29 @@ def selective_sweep_tokens_pallas(
 # --------------------------------------------------------------------------
 # the per-shard mini-batch routine (Fig. 4 body, one m)
 # --------------------------------------------------------------------------
+
+def init_field(key: jax.Array, D: int, L: int, cfg: LDAConfig, kl: int,
+               doc0, k0) -> jnp.ndarray:
+    """The random message field u0 [D, L, kl] of a shard whose documents
+    start at global index ``doc0`` and whose topics start at ``k0``.
+
+    Each document draws its [Lpad, K] field from ``fold_in(key, global
+    doc index)`` at the full K and keeps this shard's topic columns, so
+    the trajectory does not depend on how documents and topics are laid
+    over shards or chips (an N-shard run and a 1-shard run start alike).
+    cfg.init_pad_len: the field is drawn at a fixed padded length and
+    sliced, so phi_acc is invariant to the L bucket this batch landed in
+    (shape-bucketed streaming; padding slots have zero counts).
+    """
+    K = cfg.num_topics
+    Lpad = L if cfg.init_pad_len is None else max(cfg.init_pad_len, L)
+    u = jax.vmap(lambda d: jax.random.uniform(
+        jax.random.fold_in(key, d), (Lpad, K), minval=0.01, maxval=1.0))(
+            doc0 + jnp.arange(D, dtype=jnp.int32))[:, :L]
+    if kl != K:
+        u = jax.lax.dynamic_slice_in_dim(u, k0, kl, axis=2)
+    return u
+
 
 @dataclasses.dataclass
 class MinibatchResult:
@@ -538,18 +571,20 @@ def pobp_minibatch(
     phi_wire = (jnp.bfloat16 if cfg.phi_acc_dtype == "bfloat16" else None)
 
     # ---- lines 3-8: random init, local stats, first dense update ----
-    # cfg.init_pad_len: draw the random field at a fixed padded length and
-    # slice, so phi_acc is invariant to the L bucket this batch landed in
-    # (shape-bucketed streaming; padding slots have zero counts).
     D, L = batch.word_ids.shape
-    Lpad = L if cfg.init_pad_len is None else max(cfg.init_pad_len, L)
-    u0 = jax.random.uniform(key, (D, Lpad, Kl), minval=0.01, maxval=1.0)[:, :L]
+    u0 = init_field(key, D, L, cfg, Kl,
+                    data_reducer.shard_index() * D,
+                    model_reducer.shard_index() * Kl)
     mu0 = u0 / model_reducer.psum(jnp.sum(u0, -1, keepdims=True), "model_norm",
                                   compress=False)
     delta_local0 = token_scatter_wk(batch.word_ids, batch.counts[..., None] * mu0, W)
     phi_eff = phi_acc_wk + delta_local0          # local phi^0 (Fig. 4 line 5)
     phi_tot = jnp.sum(phi_eff, axis=0)
-    if cfg.impl == "pallas" and isinstance(model_reducer, LocalReducer):
+    pallas_dense = cfg.impl == "pallas" and isinstance(model_reducer,
+                                                       LocalReducer)
+    if cfg.impl == "pallas" and not pallas_dense:
+        note_bypass("dense_sweep", dict(D=D, L=L, Kl=Kl))
+    if pallas_dense:
         # fused Pallas kernel (normalization in-kernel => K must be unsharded)
         from repro.kernels.bp_update.ops import dense_sweep_pallas
         mu1, r_wk_local = dense_sweep_pallas(batch, mu0, phi_eff, phi_tot, cfg,
@@ -566,7 +601,7 @@ def pobp_minibatch(
     phi_tot = jnp.sum(phi_eff, axis=0)
     r_glob = data_reducer.psum(r_wk_local, "dense", w_rows=W,
                                dtype=phi_wire)
-    theta = jnp.einsum("dl,dlk->dk", batch.counts, mu1)
+    theta = jnp.einsum("dl,dlk->dk", batch.counts, mu1, precision=HIGHEST)
     r_w = model_reducer.psum(jnp.sum(r_glob, axis=1), "model_rw",
                              compress=False, w_rows=W)
 
@@ -645,7 +680,8 @@ def pobp_minibatch(
                 "dense_loop", w_rows=W, dtype=phi_wire)
             phi_eff = phi_acc_wk + delta
             phi_tot = jnp.sum(phi_eff, axis=0)
-            theta = jnp.einsum("dl,dlk->dk", batch.counts, mu)
+            theta = jnp.einsum("dl,dlk->dk", batch.counts, mu,
+                               precision=HIGHEST)
             r_w_c = model_reducer.psum(
                 jnp.sum(data_reducer.psum(r_wk, "dense_loop", w_rows=W,
                                           dtype=phi_wire),
@@ -814,11 +850,10 @@ def make_train_step(cfg: LDAConfig, num_shards: int = 1,
                                                   state.phi_acc, sub, weight,
                                                   live_w, decay)
         else:
-            keys = jax.random.split(sub, num_shards)
             phi, iters, mean_r, _mu, theta = jax.vmap(
-                body, in_axes=(0, 0, None, 0, None, None, None),
+                body, in_axes=(0, 0, None, None, None, None, None),
                 axis_name="shards")(
-                    word_ids, counts, state.phi_acc, keys, weight, live_w,
+                    word_ids, counts, state.phi_acc, sub, weight, live_w,
                     decay)
             # shard-identical by construction: carry shard 0's copy
             phi, iters, mean_r = phi[0], iters[0], mean_r[0]
@@ -857,9 +892,8 @@ def make_sim_minibatch_fn(cfg: LDAConfig, num_shards: int, sync_mode: str = "pow
     def fn(word_ids, counts, phi_acc, key, delta_weight):
         if num_shards == 1:
             return per_shard(word_ids, counts, phi_acc, key, delta_weight)
-        keys = jax.random.split(key, num_shards)
-        return jax.vmap(per_shard, in_axes=(0, 0, None, 0, None),
-                        axis_name="shards")(word_ids, counts, phi_acc, keys,
+        return jax.vmap(per_shard, in_axes=(0, 0, None, None, None),
+                        axis_name="shards")(word_ids, counts, phi_acc, key,
                                             delta_weight)
 
     return jax.jit(fn), meter
@@ -867,7 +901,8 @@ def make_sim_minibatch_fn(cfg: LDAConfig, num_shards: int, sync_mode: str = "pow
 
 def make_mesh_shard_fn(cfg: LDAConfig, mesh_axis_names, sync_mode: str = "power",
                        sync_dtype=jnp.float32, meter: Optional[CommMeter] = None,
-                       with_decay: bool = False, reducer_factory=None):
+                       with_decay: bool = False, reducer_factory=None,
+                       topic_shards: Optional[int] = None):
     """Per-shard POBP body for ``shard_map`` on a production mesh: documents
     sharded over the data (and pod) axes, topics over the 'model' axis.
 
@@ -883,7 +918,9 @@ def make_mesh_shard_fn(cfg: LDAConfig, mesh_axis_names, sync_mode: str = "power"
     the default ``MeshReducer`` for the DATA reducer (the vocabulary-row
     sync the parameter-server mode reroutes); the model-axis reducer is
     always a plain mesh psum — topic shards of one worker live on one
-    host and never cross the PS wire.
+    host and never cross the PS wire.  ``topic_shards=1`` (a 'model' axis
+    of one device) keeps the topic axis local instead: a `LocalReducer`,
+    so the fused Pallas dense sweep runs as it does on one chip.
     """
     dp = tuple(a for a in mesh_axis_names if a in ("pod", "data"))
     meter = meter or CommMeter()
@@ -893,7 +930,11 @@ def make_mesh_shard_fn(cfg: LDAConfig, mesh_axis_names, sync_mode: str = "power"
             data_red = reducer_factory(dp, meter, sync_dtype)
         else:
             data_red = MeshReducer(dp, meter=meter, sync_dtype=sync_dtype)
-        model_red = MeshReducer("model", meter=meter, sync_dtype=sync_dtype)
+        if topic_shards == 1:
+            model_red = LocalReducer(meter=meter, sync_dtype=sync_dtype)
+        else:
+            model_red = MeshReducer("model", meter=meter,
+                                    sync_dtype=sync_dtype)
         phi, iters, mean_r, _mu, _theta = pobp_shard_body(
             wid, cnt, phi_acc, key, delta_weight, cfg, data_red, model_red,
             sync_mode=sync_mode, decay=decay)
@@ -921,20 +962,20 @@ def shard_map_minibatch_fn(cfg: LDAConfig, mesh, sync_mode: str = "power",
     ``with_decay=True`` appends the replicated RM-retention scalar (§14).
     Returns (fn, meter).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     local, meter = make_mesh_shard_fn(cfg, mesh.axis_names, sync_mode,
                                       sync_dtype, meter,
-                                      with_decay=with_decay)
+                                      with_decay=with_decay,
+                                      topic_shards=mesh.shape["model"])
     dp = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
     in_specs = (P(dp, None), P(dp, None), P(None, "model"), P(), P())
     if with_decay:
         in_specs += (P(),)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=in_specs,
-                   out_specs=(P(None, "model"), P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=in_specs,
+                       out_specs=(P(None, "model"), P(), P()),
+                       check_vma=False)
     return fn, meter
 
 

@@ -18,19 +18,22 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.types import LDAConfig, MiniBatch
+from repro.core.types import HIGHEST, LDAConfig, MiniBatch
 
 
 def init_messages(key: jax.Array, batch: MiniBatch, K: int) -> jnp.ndarray:
-    """Random normalized messages mu[D, L, K] (Fig. 4 line 3)."""
+    """Random normalized messages mu[D, L, K] (Fig. 4 line 3); document d
+    draws its [L, K] field from ``fold_in(key, d)``."""
     D, L = batch.word_ids.shape
-    u = jax.random.uniform(key, (D, L, K), minval=0.01, maxval=1.0)
+    u = jax.vmap(lambda d: jax.random.uniform(
+        jax.random.fold_in(key, d), (L, K), minval=0.01, maxval=1.0))(
+            jnp.arange(D, dtype=jnp.int32))
     return u / jnp.sum(u, axis=-1, keepdims=True)
 
 
 def theta_hat_from(batch: MiniBatch, mu: jnp.ndarray) -> jnp.ndarray:
     """Eq. (2) inclusive form: theta_hat[d, k] = sum_l c[d,l] mu[d,l,k]."""
-    return jnp.einsum("dl,dlk->dk", batch.counts, mu)
+    return jnp.einsum("dl,dlk->dk", batch.counts, mu, precision=HIGHEST)
 
 
 def phi_delta_from(batch: MiniBatch, mu: jnp.ndarray, W: int) -> jnp.ndarray:
@@ -106,6 +109,7 @@ def log_likelihood(batch: MiniBatch, theta: jnp.ndarray, phi: jnp.ndarray,
     phi_n = (phi + cfg.beta)
     phi_n = phi_n / jnp.sum(phi_n, axis=1, keepdims=True)               # [K, W]
     p_tok = jnp.einsum("dk,kdl->dl", theta_n,
-                       jnp.take(phi_n, batch.word_ids, axis=1))         # [D, L]
+                       jnp.take(phi_n, batch.word_ids, axis=1),
+                       precision=HIGHEST)                                # [D, L]
     logp = jnp.where(batch.counts > 0, jnp.log(jnp.maximum(p_tok, 1e-30)), 0.0)
     return jnp.sum(batch.counts * logp)

@@ -26,6 +26,18 @@ Both produce the same packed [P, Pk] sync buffers, so the Eq. 6
 communication (CommMeter bytes) is invariant to the choice — pinned by
 tests/test_sweep_policy.py.
 
+A fourth resolution exists only on the pallas side:
+
+  - **xla**: the dense-layout formulation in plain XLA ops, for shapes
+    the carry kernels should not take — their one-hot MXU gathers cost
+    (4*P1 + 2*D) MACs per token-topic (P1 power rows, D documents), and
+    past ``ONEHOT_MACS_MAX`` that work exceeds the XLA formulation's
+    memory-bound passes (at NYTimes width, P1 ~ 10k, it is ~10x over);
+    a shape whose tables do not fit the VMEM budget at any block width
+    lands here too.  Every such resolution is printed once per shape and
+    recorded in ``DISPATCH_LOG`` — the kernel is never silently skipped
+    and never left to fail in Mosaic.
+
 ``resolve_sweep_policy`` picks the cheaper formulation per (T, K, Pk, P)
 at trace time from a **measured** cost model: four per-element machine
 rates (fused elementwise pass, compare-select chain term, row scatter-add,
@@ -177,21 +189,108 @@ def _pad_to(n: int, m: int) -> int:
     return -(-int(n) // m) * m
 
 
+# One-hot MACs per token-topic above which the carry kernels' MXU gathers
+# and scatters cost more than the XLA dense-layout formulation.  Analytic,
+# for TPU v5e: HIGHEST-precision f32 contractions run at ~1/6 of the
+# 197 TFLOP/s bf16 peak (~33 TFLOP/s), and the XLA iteration moves
+# ~200 B per token-topic at 819 GB/s (~0.24 ns); 2 * MACs / 33e12 s
+# equals that at ~4k MACs.  Not yet calibrated on the chip.
+ONEHOT_MACS_MAX = 4096
+
+# (where, shape, resolution, reason) for every dispatch that skipped a
+# Pallas kernel, one entry per distinct shape; `note` appends and prints
+DISPATCH_LOG: list = []
+
+
+def note(where: str, shape: dict, resolution: str, reason: str) -> None:
+    """Record (and print once) a dispatch that bypassed a Pallas kernel."""
+    entry = dict(where=where, shape=shape, resolution=resolution,
+                 reason=reason)
+    if entry in DISPATCH_LOG:
+        return
+    DISPATCH_LOG.append(entry)
+    dims = " ".join(f"{k}={v}" for k, v in shape.items())
+    print(f"[dispatch] {where} {dims} -> {resolution}: {reason}",
+          flush=True)
+
+
+def note_bypass(where: str, shape: dict) -> None:
+    """Record that impl='pallas' ran the XLA path by design: the fused
+    kernels normalize over the whole topic axis in-kernel, so a
+    topic-sharded ("model"-axis) reducer takes the jnp formulation."""
+    note(where, shape, "xla",
+         "topic-sharded model reducer (kernels need an unsharded K)")
+
+
+def carry_onehot_macs(P: int, n_docs: int, update_phi: bool = True) -> int:
+    """One-hot MACs per token-topic of the carry kernel at LOGICAL shapes:
+    phi and mask gathers plus delta and residual scatters over the padded
+    P+1 power rows, theta gather and scatter over the documents (serving
+    streams phi and scatters a per-doc residual instead)."""
+    docs = _pad_to(max(n_docs, 1), 8)
+    if not update_phi:
+        return 3 * docs
+    return 4 * _pad_to(int(P) + 1, 8) + 2 * docs
+
+
 def carry_vmem_fit(K: int, P: int, n_docs: int,
-                   vmem_budget_bytes=None) -> bool:
-    """Dispatch-side VMEM-fit predicate for the one-pass carry kernel.
+                   vmem_budget_bytes=None, update_phi: bool = True,
+                   k_width: Optional[int] = None) -> bool:
+    """Dispatch-side VMEM-fit predicate for the carry kernel.
 
     Takes LOGICAL shapes (K topics, P power rows, n_docs documents) and
     applies the kernel's padding contract (K to 128 lanes, rows/docs to
     8 sublanes plus the guard row) before asking
     `kernels.power_sweep.kernel.carry_vmem_fits` whether the footprint
-    admits a >= 64 token tile within the budget.
+    admits a >= 64 token tile within the budget — at the full K, or at
+    topic-block width ``k_width`` (the K-blocked kernel's narrowest is
+    128).  Serving (``update_phi=False``) streams phi per token, so P
+    does not enter its footprint.
     """
     from repro.kernels.power_sweep.kernel import carry_vmem_fits
-    return carry_vmem_fits(_pad_to(max(K, 1), 128),
-                           _pad_to(int(P) + 1, 8),
-                           _pad_to(max(n_docs, 1), 8),
-                           vmem_budget_bytes)
+    width = k_width or _pad_to(max(K, 1), 128)
+    rows = _pad_to(int(P) + 1, 8) if update_phi else 0
+    return carry_vmem_fits(width, rows, _pad_to(max(n_docs, 1), 8),
+                           vmem_budget_bytes, update_phi=update_phi)
+
+
+def _carry_choice(K: int, P: int, n_docs: int, policy: str, budget: int,
+                  update_phi: bool):
+    """(resolution, reason) behind `resolve_carry`."""
+    macs = carry_onehot_macs(P, n_docs, update_phi)
+    if macs > ONEHOT_MACS_MAX:
+        return "xla", (f"carry kernel one-hot work {macs} MACs per "
+                       f"token-topic > ONEHOT_MACS_MAX={ONEHOT_MACS_MAX}")
+    if policy != "kblocked" and carry_vmem_fit(K, P, n_docs, budget,
+                                               update_phi):
+        return "dense_layout", ""
+    if carry_vmem_fit(K, P, n_docs, budget, update_phi, k_width=128):
+        return "kblocked", ""
+    return "xla", (f"carry tables do not fit the {budget:,} B VMEM budget "
+                   f"even at topic block 128")
+
+
+def resolve_carry(K: int, P: int, n_docs: int, policy: str = "auto",
+                  vmem_budget_bytes=None, update_phi: bool = True) -> str:
+    """The carry formulation for the pallas impl: 'dense_layout' (the
+    one-pass kernel), 'kblocked', or 'xla' when the kernel's one-hot work
+    or VMEM footprint rules it out.  ``policy`` 'kblocked' pins the
+    K-blocked kernel where it can run at all."""
+    from repro.kernels import vmem_budget
+    return _carry_choice(K, P, n_docs, policy, vmem_budget(vmem_budget_bytes),
+                         update_phi)[0]
+
+
+def resolve_fold_in(K: int, n_docs: int, policy: str = "auto",
+                    vmem_budget_bytes=None) -> str:
+    """Serving-side resolution of the Pallas fold-in (update_phi=False):
+    same rule as training; an 'xla' result is recorded in the log."""
+    from repro.kernels import vmem_budget
+    got, why = _carry_choice(K, 0, n_docs, policy,
+                             vmem_budget(vmem_budget_bytes), False)
+    if got == "xla":
+        note("fold_in", dict(K=K, D=n_docs), got, why)
+    return got
 
 
 @functools.lru_cache(maxsize=512)
@@ -209,11 +308,15 @@ def _resolve_cached(policy: str, T: int, K: int, Pk: int, P: int,
         # one HBM read + one write of the [T, K] carry per iteration, all
         # one-hot work on the MXU (kernels/power_sweep).  When the full-K
         # carry footprint stops admitting a useful token tile, the
-        # K-blocked two-pass variant takes over (DESIGN.md §13).  The
-        # packed kernel path remains reachable via sweep_policy='packed'.
-        if carry_vmem_fit(K, P, n_docs, budget):
-            return "dense_layout"
-        return "kblocked"
+        # K-blocked two-pass variant takes over (DESIGN.md §13); when
+        # the one-hot work or the tables rule both out, the XLA
+        # dense-layout formulation runs, and says so.  The packed kernel
+        # path remains reachable via sweep_policy='packed'.
+        got, why = _carry_choice(K, P, n_docs, "auto", budget, True)
+        if got == "xla":
+            note("selective_sweep", dict(T=T, K=K, Pk=Pk, P=P, D=n_docs),
+                 got, why)
+        return got
     c = measure_coeffs()
     cp = packed_cost(T, K, Pk, P, crossover, c)
     cd = dense_layout_cost(T, K, Pk, P, c)
@@ -236,7 +339,7 @@ def resolve_sweep_policy(cfg, T: int, K: int, Pk: int, P: int,
     if policy not in ("auto", "packed", "dense_layout", "kblocked"):
         raise ValueError(f"unknown sweep_policy: {policy!r} (expected "
                          f"auto | packed | dense_layout | kblocked)")
-    from repro.kernels.power_sweep.kernel import vmem_budget
+    from repro.kernels import vmem_budget
     budget = vmem_budget(getattr(cfg, "vmem_budget_bytes", None))
     return _resolve_cached(policy, int(T), int(K), int(Pk), int(P),
                            int(cfg.onehot_crossover),

@@ -222,6 +222,10 @@ class Reducer:
         out = self._sum(x)
         return out.astype(orig)
 
+    def shard_index(self) -> jnp.ndarray:
+        """This shard's position along the reduced axis (0 unsharded)."""
+        return jnp.zeros((), jnp.int32)
+
     def bill(self, x: jnp.ndarray, phase: str,
              w_rows: Optional[int] = None) -> jnp.ndarray:
         """Record a *local* full-statistic touch without reducing.
@@ -245,6 +249,9 @@ class MeshReducer(Reducer):
 
     def _sum(self, x):
         return jax.lax.psum(x, self.axis_name)
+
+    def shard_index(self):
+        return jax.lax.axis_index(self.axis_name)
 
 
 class SimReducer(Reducer):
@@ -333,6 +340,9 @@ class PSReducer(Reducer):
 
     def _sum(self, x):
         return self.inner._sum(x)
+
+    def shard_index(self):
+        return self.inner.shard_index()
 
 
 def dense_sync_bytes(W: int, K: int, itemsize: int = 4) -> int:

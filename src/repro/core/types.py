@@ -24,6 +24,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+# precision of every f32 contraction on the main path: the TPU's default
+# runs a single bf16 pass, which makes the count-weighted sums (theta,
+# the one-hot packs) inexact; HIGHEST keeps them float32 on every backend
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class LDAConfig:

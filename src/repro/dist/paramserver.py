@@ -637,7 +637,10 @@ class PSClient:
         """Exponential backoff with deterministic jitter: the sleep for
         retry ``n`` of this client is a pure function of
         ``(client_id, retry counter)`` — chaos runs stay replayable."""
-        base = min(self.backoff_max_s, self.backoff0_s * (2.0 ** attempt))
+        # the exponent is capped: past ~60 doublings every base is the cap,
+        # and 2.0 ** attempt overflows a float at attempt 1024
+        base = min(self.backoff_max_s,
+                   self.backoff0_s * (2.0 ** min(attempt, 60)))
         rng = np.random.default_rng((self._jitter_key, self._retry_counter))
         self._retry_counter += 1
         time.sleep(base * (0.5 + rng.random()))

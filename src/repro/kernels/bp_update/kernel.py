@@ -11,9 +11,10 @@ resident in VMEM, computes
 in one pass — five HBM streams (mu, theta, phi in; mu', r out) instead of the
 ~12 an unfused XLA graph issues, and zero [T, K] temporaries in HBM.
 
-Tiling: TT is chosen so 5 * TT * K * 4 bytes fits in ~12.5 MB of VMEM
-(leaving headroom of the 16 MB/core budget); K is padded to a multiple of
-128 (lane width) and TT to a multiple of 8 (sublane width) by ops.py.
+Tiling: TT is the largest power of two whose double-buffered blocks and
+in-kernel temporaries fit the shared VMEM budget (`repro.kernels`), which
+is also the kernel's Mosaic VMEM limit; K is padded to a multiple of 128
+(lane width) and TT to a multiple of 8 (sublane width) by ops.py.
 """
 
 from __future__ import annotations
@@ -42,14 +43,30 @@ def _kernel(counts_ref, mu_ref, theta_ref, phi_ref, phi_tot_ref,
     r_out_ref[...] = c * jnp.abs(mu_new - mu)
 
 
-def token_tile(k_width: int, vmem_budget_bytes: int = 12_500_000) -> int:
-    """Largest power-of-two TT in [8, 512] s.t. 5 [TT, K] f32 tiles fit VMEM.
+def vmem_bytes(tt: int, k_width: int) -> int:
+    """VMEM the kernel needs at tile TT: five [TT, K] blocks (mu, theta,
+    phi in; mu', r out) and the lane-padded [TT, 1] counts block, each
+    double-buffered by the pipeline, plus ~6 [TT, K] f32 temporaries and
+    the double-buffered [1, K] (sublane-padded to 8) phi_tot block."""
+    blocks = 2 * tt * (5 * k_width * 4 + K_.LANE_ROW_BYTES)
+    return blocks + 6 * tt * k_width * 4 + 2 * 8 * k_width * 4
+
+
+def token_tile(k_width: int, vmem_budget_bytes=None) -> int:
+    """Largest power-of-two TT in [8, 512] whose `vmem_bytes` fit the budget.
 
     Power of two so the divisibility fallback (halving until TT | T, T a
     multiple of 8) never collapses to a degenerate non-aligned tile.
     """
-    tt = max(8, min(512, vmem_budget_bytes // (5 * k_width * 4)))
-    return 1 << (tt.bit_length() - 1)
+    budget = K_.vmem_budget(vmem_budget_bytes)
+    tt = 512
+    while tt > 8 and vmem_bytes(tt, k_width) > budget:
+        tt //= 2
+    if vmem_bytes(tt, k_width) > budget:
+        raise ValueError(f"bp_update at K={k_width} needs "
+                         f"{vmem_bytes(tt, k_width):,} B of VMEM at the "
+                         f"minimum tile, over the {budget:,} B budget")
+    return tt
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "beta", "wbeta"))
@@ -64,7 +81,8 @@ def bp_update_tokens(counts_t: jnp.ndarray, mu_t: jnp.ndarray,
     Returns (mu_new [T, K], r_tok [T, K]).
     """
     T, K = mu_t.shape
-    TT = token_tile(K)
+    budget = K_.vmem_budget()
+    TT = token_tile(K, budget)
     while T % TT:
         TT //= 2
     grid = (T // TT,)
@@ -78,5 +96,6 @@ def bp_update_tokens(counts_t: jnp.ndarray, mu_t: jnp.ndarray,
         out_specs=[spec_tk, spec_tk],
         out_shape=[jax.ShapeDtypeStruct((T, K), mu_t.dtype),
                    jax.ShapeDtypeStruct((T, K), mu_t.dtype)],
+        compiler_params=K_.compiler_params(budget),
         interpret=K_.INTERPRET,
     )(counts_t, mu_t, theta_t, phi_t, phi_tot)

@@ -12,7 +12,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core.residuals import token_scatter_wk
-from repro.core.types import LDAConfig, MiniBatch, TokenLayout
+from repro.core.types import HIGHEST, LDAConfig, MiniBatch, TokenLayout
 from repro.kernels.bp_update.kernel import bp_update_tokens
 
 
@@ -38,7 +38,7 @@ def dense_sweep_pallas(batch: MiniBatch, mu: jnp.ndarray,
     D, L = batch.word_ids.shape
     K = mu.shape[-1]
     layout = layout or batch.token_layout()
-    theta = jnp.einsum("dl,dlk->dk", batch.counts, mu)
+    theta = jnp.einsum("dl,dlk->dk", batch.counts, mu, precision=HIGHEST)
     if wbeta is None:
         wb_static = cfg.vocab_size * cfg.beta
     else:

@@ -83,9 +83,9 @@ def power_sweep_carry(p_tok: jnp.ndarray, doc_ids: jnp.ndarray,
     selection, guard row all zeros.  On the serving path
     ``update_phi=False`` the selection is implicit (every row but the
     guard selects all topics — the kernel compares p_tok against the
-    guard id instead of gathering a mask, and ``mask_rows`` is replaced
-    by a dummy); ``beta`` must be 0 there so the K lane padding keeps
-    u == 0 exactly.
+    guard id instead of gathering a mask, ``mask_rows`` is replaced by a
+    dummy, and the phi rows reach the kernel pre-gathered per token);
+    ``beta`` must be 0 there so the K lane padding keeps u == 0 exactly.
 
     Padding contract (keeps the fused math exact — see kernel.py):
       - K -> lane multiple (128): mask pads 0, so padded columns carry
@@ -120,10 +120,15 @@ def power_sweep_carry(p_tok: jnp.ndarray, doc_ids: jnp.ndarray,
     mu_p = _pad_axis(_pad_axis(mu_t.astype(f32), 1, 128), 0, 8)
     th_p = _pad_axis(_pad_axis(theta.astype(f32), 1, 128), 0, 8)
     pt_p = _pad_axis(phi_tot.astype(f32).reshape(1, -1), 1, 128)
-    phi_p = _pad_axis(_pad_axis(phi_rows.astype(f32), 1, 128), 0, 8)
     if update_phi:
+        phi_p = _pad_axis(_pad_axis(phi_rows.astype(f32), 1, 128), 0, 8)
         msk_p = _pad_axis(_pad_axis(mask_rows.astype(f32), 1, 128), 0, 8)
-    else:  # implicit all-topics mask: ship a sublane-sized dummy instead
+    else:
+        # serving: the kernel streams each token's phi row ([T, K]; a
+        # whole-vocabulary table never fits VMEM) and derives the implicit
+        # all-topics mask from the guard compare — a sublane dummy mask
+        phi_tok = jnp.take(phi_rows.astype(f32), p_tok, axis=0)
+        phi_p = _pad_axis(_pad_axis(phi_tok, 1, 128), 0, 8)
         msk_p = jnp.zeros((8, phi_p.shape[1]), f32)
     c_p = _pad_axis(counts_t.astype(f32), 0, 8)
     p_tok_p = _pad_axis(p_tok.astype(jnp.int32), 0, 8, value=P)

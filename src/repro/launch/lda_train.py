@@ -47,14 +47,13 @@ consumes the stream with:
       --minibatches 24 --ckpt-dir /tmp/lda_ck --crash-at 10
   # rerun the same command: resumes from the latest checkpoint
 
-NB: jax is imported lazily so ``--backend shard_map`` can force the host
-platform device count before first jax use (same contract as dryrun.py).
+``--backend shard_map`` runs on the devices JAX finds (``--mesh-shape
+4,1`` on a four-chip host); a mesh larger than the host is an error.
 """
 
 from __future__ import annotations
 
 import argparse
-import os
 import time
 from typing import Any, Dict, Optional
 
@@ -541,12 +540,8 @@ def _run_signature(args) -> Dict[str, Any]:
 
 
 def _compiles(step_fn) -> int:
-    """Compile count via the jitted function's cache (private jax API; -1
-    when absent — BENCH_e2e asserts positivity so a break is loud)."""
-    try:
-        return int(step_fn._cache_size())
-    except AttributeError:
-        return -1
+    """Compile count via the jitted function's cache (private jax API)."""
+    return int(step_fn._cache_size())
 
 
 class _CompileClock:
@@ -695,6 +690,19 @@ def train_loop(args, on_batch=None) -> Dict[str, Any]:
                       f"(raise --minibatches or use a fresh --ckpt-dir)",
                       flush=True)
 
+    mesh = _make_mesh(args) if args.backend == "shard_map" else None
+
+    def place(state):
+        """The carry laid out as the shard_map step returns it (phi over
+        'model', the rest replicated), so the program the first call
+        compiles is the one every later call runs."""
+        if mesh is None:
+            return state
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        rep = NamedSharding(mesh, P())
+        return jax.device_put(state, LDATrainState(
+            phi_acc=NamedSharding(mesh, P(None, "model")), m=rep, rng=rep))
+
     def build_step(cfg):
         if args.backend == "sim":
             return make_train_step(cfg, args.shards, args.sync, sync_dtype)
@@ -713,7 +721,6 @@ def train_loop(args, on_batch=None) -> Dict[str, Any]:
                                  sync_dtype=sync_dtype))
             return make_train_step(cfg, args.shards, args.sync, sync_dtype,
                                    reducer=PSReducer(inner))
-        mesh = _make_mesh(args)
         return make_shardmap_train_step(cfg, mesh, args.sync, sync_dtype)
 
     def warm_buckets(step_fn, cfg):
@@ -722,7 +729,7 @@ def train_loop(args, on_batch=None) -> Dict[str, Any]:
         # on a mid-run compile (startup cost, not steady-state cost).  The
         # dynamic variant warms with a live_w argument so the compiled
         # program is the one the stream will actually run.
-        scratch = init_train_state(cfg, args.seed)
+        scratch = place(init_train_state(cfg, args.seed))
         for L in (buckets[-1:] if args.fixed_len else buckets):
             if args.backend in ("sim", "ps") and args.shards > 1:
                 shape = (args.shards, args.docs_per_batch // args.shards, L)
@@ -737,6 +744,7 @@ def train_loop(args, on_batch=None) -> Dict[str, Any]:
         jax.block_until_ready(scratch.phi_acc)
 
     step_fn, meter = build_step(cfg)
+    state = place(state)
 
     ps_server = ps_transport = touched_rows_of = None
     ps_workers: Dict[str, Any] = {}
@@ -842,9 +850,14 @@ def train_loop(args, on_batch=None) -> Dict[str, Any]:
     def eval_ppl():
         from repro.data.batching import docs_to_padded
         tr, te = heldout()
+        phi_acc = state.phi_acc
+        if args.backend == "shard_map":
+            # the held-out fold-in is a one-device program: its Pallas
+            # kernel cannot be partitioned over the mesh's shards of phi
+            phi_acc = jax.device_put(phi_acc, jax.devices()[0])
         if not dynamic:
             return perplexity.evaluate(jax.random.PRNGKey(args.seed + 1),
-                                       state.phi_acc, tr, te, cfg)
+                                       phi_acc, tr, te, cfg)
         # dynamic: the raw split lives in external-id space — remap it at
         # the CURRENT vocabulary (lookup only, OOV -> first guard row,
         # where the live-masked phi gives the beta-prior mass)
@@ -853,7 +866,7 @@ def train_loop(args, on_batch=None) -> Dict[str, Any]:
         te_b = docs_to_padded(vocab.map_docs(te, admit=False,
                                              oov_row=live_done))
         return perplexity.evaluate(jax.random.PRNGKey(args.seed + 1),
-                                   state.phi_acc, tr_b, te_b, cfg,
+                                   phi_acc, tr_b, te_b, cfg,
                                    live_w=live_done)
 
     def dyn_extra(next_m: int, live: int) -> Dict[str, Any]:
@@ -1228,12 +1241,10 @@ def train_loop(args, on_batch=None) -> Dict[str, Any]:
 
 
 def main(argv=None):
+    from repro.launch.compile_cache import use_compile_cache
+
     args = build_parser().parse_args(argv)
-    if args.backend == "shard_map" and "XLA_FLAGS" not in os.environ:
-        # must happen before first jax import (same contract as dryrun.py)
-        n = 512 if not args.mesh_shape else int(np.prod(_csv_ints(args.mesh_shape)))
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={n}")
+    use_compile_cache()
     res = train_loop(args)
     done = args.minibatches - res["first_m"]
     print(f"[done] {done} minibatches  final mean_r="

@@ -1,8 +1,7 @@
 """Production mesh construction.
 
 A FUNCTION, not a module-level constant: importing this module never
-touches jax device state (the dry-run sets XLA_FLAGS before any jax use;
-tests and benchmarks must keep seeing 1 CPU device).
+touches jax device state.
 """
 
 from __future__ import annotations
@@ -20,9 +19,7 @@ def make_production_mesh(*, multi_pod: bool = False):
         return jax.make_mesh(shape, axes)
     if len(devices) < need:
         raise RuntimeError(
-            f"mesh {shape} needs {need} devices, found {len(devices)} — "
-            "the dry-run must set XLA_FLAGS=--xla_force_host_platform_"
-            "device_count=512 before importing jax (launch/dryrun.py does).")
+            f"mesh {shape} needs {need} devices, found {len(devices)}")
     # more devices than needed (the 512-device dry-run building the 256-chip
     # single-pod mesh): use a prefix slice.
     return jax.sharding.Mesh(

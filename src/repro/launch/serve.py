@@ -252,6 +252,9 @@ def serve_lm(args):
 
 
 def main(argv=None):
+    """CLI entry; returns ``(results, stats)`` in LDA mode."""
+    from repro.launch.compile_cache import use_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default="lda", choices=["lda", "lm"])
     # lda serving
@@ -316,13 +319,13 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.batch is None:
         args.batch = 32 if args.mode == "lda" else 8
+    use_compile_cache()
     if args.mode == "lda":
         if not args.ckpt_dir:
             ap.error("--mode lda needs --ckpt-dir (train one with "
                      "`python -m repro.launch.lda_train --ckpt-dir ...`)")
-        serve_lda(args)
-    else:
-        serve_lm(args)
+        return serve_lda(args)
+    serve_lm(args)
 
 
 if __name__ == "__main__":

@@ -79,11 +79,10 @@ def moe_apply(p, x, *, cfg: ArchConfig, ctx: ShardingCtx):
             wspec["shared"] = {"wi": P_(None, mx),   # shared experts TP-split
                                "wg": P_(None, mx),
                                "wo": P_(mx, None)}
-        from jax.experimental.shard_map import shard_map
-        return shard_map(inner, mesh=mesh,
-                         in_specs=(P_(dp, None, None), wspec),
-                         out_specs=(P_(dp, None, None), P_()),
-                         check_rep=False)(x, p)
+        return jax.shard_map(inner, mesh=mesh,
+                             in_specs=(P_(dp, None, None), wspec),
+                             out_specs=(P_(dp, None, None), P_()),
+                             check_vma=False)(x, p)
     return _moe_apply_local(p, x, cfg=cfg, ctx=ctx)
 
 

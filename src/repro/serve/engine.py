@@ -214,12 +214,9 @@ def _load_serving_checkpoint(ckpt_dir: str, cfg: Optional[LDAConfig],
         # signature only routes the knobs the fold-in body reads —
         # impl (jnp vs Pallas) and sync_dtype (reducer payload width)
         if not run:
-            import warnings
-            warnings.warn(
-                f"checkpoint in {ckpt_dir!r} carries no run signature; "
-                f"serving with impl='jnp' sync_dtype='float32' — pass "
-                f"cfg= if the model was trained with other knobs",
-                stacklevel=2)
+            raise ValueError(
+                f"checkpoint in {ckpt_dir!r} carries no run signature, so "
+                f"the kernel path it was trained for is unknown; pass cfg=")
         cfg = LDAConfig(vocab_size=int(phi_acc.shape[0]),
                         num_topics=int(phi_acc.shape[1]),
                         impl=str(run.get("impl", "jnp")),
@@ -512,11 +509,7 @@ class FoldInEngine:
         results: List[ServeResult] = []
         while self._pending:
             head = self._pending[0]
-            try:
-                ready = head.theta.is_ready()
-            except AttributeError:      # older jax: no readiness probe
-                break
-            if not ready:
+            if not head.theta.is_ready():
                 break
             results.extend(self._materialize(head))
             self._pending.pop(0)
@@ -530,10 +523,7 @@ class FoldInEngine:
     # -------------------------------------------------------------- stats
 
     def _compiles(self) -> int:
-        try:
-            return int(self._step._cache_size())
-        except AttributeError:
-            return -1
+        return int(self._step._cache_size())
 
     def stats(self) -> Dict[str, object]:
         """Serving scorecard: docs/s, latency percentiles, compile bound,
@@ -896,12 +886,8 @@ class SlabEngine:
         n = 0
         while self._pending:
             head = self._pending[0]
-            if not block:
-                try:
-                    if not head.retired.is_ready():
-                        break
-                except AttributeError:
-                    pass             # no readiness probe: fall through
+            if not block and not head.retired.is_ready():
+                break
             self._pending.popleft()
             n += self._materialize(head)
             block = False            # only the first is forced
@@ -1056,10 +1042,7 @@ class SlabEngine:
         return self._rates
 
     def _compiles(self) -> int:
-        try:
-            return int(self._step._cache_size())
-        except AttributeError:
-            return -1
+        return int(self._step._cache_size())
 
     def stats(self) -> Dict[str, object]:
         """Serving scorecard (superset of the bucket engine's): goodput,

@@ -68,6 +68,24 @@ def test_pobp_shards_agree_on_global_state(corpus):
                                    np.asarray(phi_new[n]), rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("n_data,n_model", [(2, 1), (4, 1), (2, 2)])
+def test_init_field_is_layout_invariant(n_data, n_model):
+    """Each shard's random message field is its slice of the one global
+    field — documents by global index, topics by global column — so a
+    run's start does not depend on how documents and topics are laid
+    over shards or chips."""
+    from repro.core.pobp import init_field
+    D, L, K = 8, 5, CFG.num_topics
+    key = jax.random.PRNGKey(11)
+    full = np.asarray(init_field(key, D, L, CFG, K, 0, 0))
+    dl, kl = D // n_data, K // n_model
+    for i in range(n_data):
+        for j in range(n_model):
+            part = init_field(key, dl, L, CFG, kl, i * dl, j * kl)
+            np.testing.assert_array_equal(
+                np.asarray(part), full[i * dl:(i + 1) * dl, :, j * kl:(j + 1) * kl])
+
+
 def test_dense_vs_power_converge_to_similar_perplexity(corpus):
     """The paper's core accuracy claim: sparse power sync (Eq. 6) must not
     cost much accuracy vs dense sync (Eq. 4) at lambda_w ~ 0.3."""

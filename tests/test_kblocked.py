@@ -155,8 +155,9 @@ def test_kblocked_policy_on_jnp_is_dense_layout():
 
 def test_pallas_auto_flips_on_vmem_fit():
     """auto -> dense_layout while the full-K carry fits, kblocked beyond;
-    the flip is driven purely by the budget."""
-    big, small = 10**9, 500_000
+    the flip is driven purely by the budget (``small`` fits the K-blocked
+    kernel's 128-wide topic blocks, not the full-K tables)."""
+    big, small = 10**9, 4_000_000
     assert carry_vmem_fit(1024, 48, 224, big)
     assert not carry_vmem_fit(1024, 48, 224, small)
     kw = dict(T=4096, K=1024, Pk=16, P=48, crossover=8_000_000,
@@ -167,7 +168,7 @@ def test_pallas_auto_flips_on_vmem_fit():
 
 def test_cfg_budget_reaches_dispatch():
     cfg = LDAConfig(vocab_size=100, num_topics=1024, impl="pallas",
-                    sweep_policy="auto", vmem_budget_bytes=500_000)
+                    sweep_policy="auto", vmem_budget_bytes=4_000_000)
     assert resolve_sweep_policy(cfg, 4096, 1024, 16, 48,
                                 n_docs=224) == "kblocked"
     cfg2 = dataclasses.replace(cfg, vmem_budget_bytes=None)

@@ -56,10 +56,16 @@ def test_bp_update_dtype_bf16():
 
 
 def test_token_tile_fits_vmem():
+    """The tile fits the shared VMEM budget — which is also the kernel's
+    Mosaic VMEM limit — with its blocks double-buffered, and is the
+    largest power of two that does."""
+    from repro.kernels import vmem_budget
+    from repro.kernels.bp_update.kernel import vmem_bytes
     for K in (128, 512, 2048, 4096, 10240):
         tt = token_tile(K)
         assert tt % 8 == 0 and tt >= 8
-        assert 5 * tt * K * 4 <= 16 * 1024 * 1024  # hard VMEM budget
+        assert vmem_bytes(tt, K) <= vmem_budget()
+        assert tt == 512 or vmem_bytes(2 * tt, K) > vmem_budget()
 
 
 def test_dense_sweep_pallas_matches_jnp_sweep():

@@ -47,8 +47,12 @@ def test_power_sweep_kernel_matches_ref(T, P, Pk):
     p_tok = jnp.asarray(rng.integers(0, P + 1, T).astype(np.int32))
     c = jnp.asarray(rng.integers(0, 4, (T, 1)).astype(np.float32))
     mu_sel = jnp.asarray(rng.uniform(0.01, 1, (T, Pk)).astype(np.float32))
-    th = jnp.asarray(rng.uniform(0, 5, (T, Pk)).astype(np.float32))
-    pt = jnp.asarray(rng.uniform(1, 9, (T, Pk)).astype(np.float32))
+    # theta and phi_tot include each token's own count mass (c * mu), as
+    # every real statistic does; without it the self-count subtraction
+    # drives the denominator through zero and the outputs blow up
+    self_c = np.asarray(c) * np.asarray(mu_sel)
+    th = jnp.asarray(rng.uniform(0, 5, (T, Pk)).astype(np.float32) + self_c)
+    pt = jnp.asarray(rng.uniform(1, 9, (T, Pk)).astype(np.float32) + self_c)
     phip = jnp.asarray(rng.uniform(0, 5, (P, Pk)).astype(np.float32))
     kw = dict(alpha=0.1, beta=0.01, wbeta=0.4)
     mu1, d1, r1 = power_sweep(p_tok, c, mu_sel, th, pt, phip, **kw)

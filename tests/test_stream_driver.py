@@ -258,8 +258,8 @@ def test_prefetch_worker_exception_propagates():
 
 def test_driver_shard_map_backend_smoke():
     """The driver's --backend shard_map executes the SAME per-shard body the
-    dryrun cell compiles (make_mesh_shard_fn) on a real (forced-host) mesh.
-    Subprocess: the device count must be locked before first jax import."""
+    dryrun cell compiles (make_mesh_shard_fn) on an 8-device CPU mesh.
+    Subprocess: the host device count is fixed before jax starts."""
     import os
     import subprocess
     import sys
@@ -273,10 +273,12 @@ def test_driver_shard_map_backend_smoke():
          "--minibatches", "2", "--docs-per-batch", "16", "--vocab", "64",
          "--topics", "8", "--lambda-k", "4", "--inner-iters", "3",
          "--log-every", "1", "--no-warmup-buckets"],
-        capture_output=True, text=True, timeout=300,
+        env=env, capture_output=True, text=True, timeout=300,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert out.returncode == 0, out.stderr[-2000:]
     assert "[done] 2 minibatches" in out.stdout
+    # the carry is placed as the step returns it: one program, no recompile
+    assert "compiles=2" not in out.stdout
     # topic-sharded phases must appear (model-axis psums are real here),
     # including the per-iteration loop phase billed by per_minibatch_bytes
     assert "model_norm" in out.stdout and "model_rw" in out.stdout
