@@ -405,91 +405,95 @@ def make_slab_step(cfg: LDAConfig, *, slots: int, slot_len: int,
 
     def body(phi_norm, state: SlabState, refill_rows, refill_cnt,
              refill_slot, warm_theta, warm_mask, key):
-        valid = refill_slot < B                                    # [R]
-        wid = state.word_rows.at[refill_slot].set(refill_rows, mode="drop")
-        cnt = state.counts.at[refill_slot].set(refill_cnt, mode="drop")
-        live = state.live.at[refill_slot].set(valid, mode="drop")
+        # named scopes give the step's ops an owner on a device trace
+        with jax.named_scope("slab.refill"):
+            valid = refill_slot < B                                    # [R]
+            wid = state.word_rows.at[refill_slot].set(refill_rows, mode="drop")
+            cnt = state.counts.at[refill_slot].set(refill_cnt, mode="drop")
+            live = state.live.at[refill_slot].set(valid, mode="drop")
 
-        # ---- fresh init for refilled slots (in-step, per-slot random) --
-        # drawn at the GLOBAL K and sliced per topic shard, the same
-        # K-invariant contract as _init_messages; warm-started slots seed
-        # their messages from the cached theta instead (one BP half-step:
-        # m_l ∝ theta_cached * phi_w_l), which restarts the fold-in near
-        # the cached posterior so the residual bound clears in fewer sweeps
-        u = jax.random.uniform(key, (R, L, K), minval=0.01, maxval=1.0)
-        if Kl != K:
-            idx = jax.lax.axis_index("model")
-            u = jax.lax.dynamic_slice_in_dim(u, idx * Kl, Kl, axis=2)
-            warm_theta = jax.lax.dynamic_slice_in_dim(
-                warm_theta, idx * Kl, Kl, axis=1)
-        phi_new = jnp.take(phi_norm, refill_rows.reshape(-1),
-                           axis=0).reshape(R, L, Kl)
-        warm_u = warm_theta[:, None, :] * phi_new                 # [R, L, Kl]
-        u = jnp.where(warm_mask[:, None, None], warm_u, u)
-        norm0 = reducer.psum(jnp.sum(u, -1, keepdims=True),
-                             "slab_init_norm", compress=False)
-        mu0 = u / jnp.maximum(norm0, 1e-30)
-        c_new = refill_cnt[..., None]                             # [R, L, 1]
-        theta0 = jnp.sum(c_new * mu0, axis=1)                     # [R, Kl]
+            # ---- fresh init for refilled slots (in-step, per-slot random) --
+            # drawn at the GLOBAL K and sliced per topic shard, the same
+            # K-invariant contract as _init_messages; warm-started slots seed
+            # their messages from the cached theta instead (one BP half-step:
+            # m_l ∝ theta_cached * phi_w_l), which restarts the fold-in near
+            # the cached posterior so the residual bound clears in fewer sweeps
+            u = jax.random.uniform(key, (R, L, K), minval=0.01, maxval=1.0)
+            if Kl != K:
+                idx = jax.lax.axis_index("model")
+                u = jax.lax.dynamic_slice_in_dim(u, idx * Kl, Kl, axis=2)
+                warm_theta = jax.lax.dynamic_slice_in_dim(
+                    warm_theta, idx * Kl, Kl, axis=1)
+            phi_new = jnp.take(phi_norm, refill_rows.reshape(-1),
+                               axis=0).reshape(R, L, Kl)
+            warm_u = warm_theta[:, None, :] * phi_new             # [R, L, Kl]
+            u = jnp.where(warm_mask[:, None, None], warm_u, u)
+            norm0 = reducer.psum(jnp.sum(u, -1, keepdims=True),
+                                 "slab_init_norm", compress=False)
+            mu0 = u / jnp.maximum(norm0, 1e-30)
+            c_new = refill_cnt[..., None]                         # [R, L, 1]
+            theta0 = jnp.sum(c_new * mu0, axis=1)                     # [R, Kl]
 
-        mu = state.mu.reshape(B, L, Kl).at[refill_slot].set(
-            mu0, mode="drop").reshape(B * L, Kl)
-        theta = state.theta.at[refill_slot].set(theta0, mode="drop")
-        r_doc = state.r_doc.at[refill_slot].set(
-            jnp.where(valid, jnp.inf, 0.0), mode="drop")
-        r_prev = state.r_prev.at[refill_slot].set(1.0, mode="drop")
-        it = state.it.at[refill_slot].set(0, mode="drop")
+            mu = state.mu.reshape(B, L, Kl).at[refill_slot].set(
+                mu0, mode="drop").reshape(B * L, Kl)
+            theta = state.theta.at[refill_slot].set(theta0, mode="drop")
+            r_doc = state.r_doc.at[refill_slot].set(
+                jnp.where(valid, jnp.inf, 0.0), mode="drop")
+            r_prev = state.r_prev.at[refill_slot].set(1.0, mode="drop")
+            it = state.it.at[refill_slot].set(0, mode="drop")
 
         # ---- iterate: sweeps_per_step token-major fold-in sweeps -------
-        c = cnt.reshape(B * L, 1)
-        tok_d = cnt.sum(axis=1)                                    # [B]
-        wid_t = wid.reshape(B * L)
-        phi_tok = jnp.take(phi_norm, wid_t, axis=0)                # [T, Kl]
-        if use_pallas:
-            from repro.kernels.power_sweep.ops import power_sweep_carry
-            w_rows = phi_norm.shape[0]
-            phi_rows = jnp.concatenate(
-                [phi_norm, jnp.zeros((1, Kl), phi_norm.dtype)], axis=0)
-            mask_dummy = jnp.zeros((1, Kl), jnp.float32)
-            pt_zero = jnp.zeros((Kl,), jnp.float32)
-            kblocked = carry == "kblocked"
-        for _ in range(sweeps_per_step):
-            act_d = active_slots(r_doc, r_prev, it, live, tok_d)   # [B]
-            act_tok = act_d[doc_ids]                               # [T]
+        with jax.named_scope("slab.sweeps"):
+            c = cnt.reshape(B * L, 1)
+            tok_d = cnt.sum(axis=1)                                    # [B]
+            wid_t = wid.reshape(B * L)
+            phi_tok = jnp.take(phi_norm, wid_t, axis=0)            # [T, Kl]
             if use_pallas:
-                p_tok = jnp.where(act_tok, wid_t, w_rows).astype(jnp.int32)
-                mu_new, th_delta, _, _, r_local = power_sweep_carry(
-                    p_tok, doc_ids, c, mu, theta, pt_zero,
-                    phi_rows, mask_dummy, alpha=cfg.alpha, beta=0.0,
-                    wbeta=1.0, update_phi=False, kblocked=kblocked,
-                    vmem_budget_bytes=cfg.vmem_budget_bytes)
-                theta = theta + th_delta
-            else:
-                th = theta[doc_ids] - c * mu + cfg.alpha
-                unnorm = th * phi_tok
-                norm = reducer.psum(jnp.sum(unnorm, -1, keepdims=True),
-                                    "slab_norm_loop", compress=False)
-                mu_new = unnorm / jnp.maximum(norm, 1e-30)
-                mu_new = jnp.where(act_tok[:, None], mu_new, mu)
-                delta = mu_new - mu
-                theta = theta + (c * delta).reshape(B, L, Kl).sum(axis=1)
-                r_local = (c * jnp.abs(delta)).reshape(B, L, Kl).sum(
-                    axis=(1, 2))
-            r_new = reducer.psum(r_local, "slab_rw_loop", compress=False)
-            r_prev = jnp.where(act_d, r_doc, r_prev)
-            r_doc = jnp.where(act_d, r_new, r_doc)
-            it = it + act_d.astype(jnp.int32)
-            mu = mu_new
+                from repro.kernels.power_sweep.ops import power_sweep_carry
+                w_rows = phi_norm.shape[0]
+                phi_rows = jnp.concatenate(
+                    [phi_norm, jnp.zeros((1, Kl), phi_norm.dtype)], axis=0)
+                mask_dummy = jnp.zeros((1, Kl), jnp.float32)
+                pt_zero = jnp.zeros((Kl,), jnp.float32)
+                kblocked = carry == "kblocked"
+            for _ in range(sweeps_per_step):
+                act_d = active_slots(r_doc, r_prev, it, live, tok_d)   # [B]
+                act_tok = act_d[doc_ids]                               # [T]
+                if use_pallas:
+                    p_tok = jnp.where(act_tok, wid_t, w_rows).astype(jnp.int32)
+                    mu_new, th_delta, _, _, r_local = power_sweep_carry(
+                        p_tok, doc_ids, c, mu, theta, pt_zero,
+                        phi_rows, mask_dummy, alpha=cfg.alpha, beta=0.0,
+                        wbeta=1.0, update_phi=False, kblocked=kblocked,
+                        vmem_budget_bytes=cfg.vmem_budget_bytes)
+                    theta = theta + th_delta
+                else:
+                    th = theta[doc_ids] - c * mu + cfg.alpha
+                    unnorm = th * phi_tok
+                    norm = reducer.psum(jnp.sum(unnorm, -1, keepdims=True),
+                                        "slab_norm_loop", compress=False)
+                    mu_new = unnorm / jnp.maximum(norm, 1e-30)
+                    mu_new = jnp.where(act_tok[:, None], mu_new, mu)
+                    delta = mu_new - mu
+                    theta = theta + (c * delta).reshape(B, L, Kl).sum(axis=1)
+                    r_local = (c * jnp.abs(delta)).reshape(B, L, Kl).sum(
+                        axis=(1, 2))
+                r_new = reducer.psum(r_local, "slab_rw_loop", compress=False)
+                r_prev = jnp.where(act_d, r_doc, r_prev)
+                r_doc = jnp.where(act_d, r_new, r_doc)
+                it = it + act_d.astype(jnp.int32)
+                mu = mu_new
 
         # ---- retire: live slots whose residual bound cleared -----------
-        still = active_slots(r_doc, r_prev, it, live, tok_d)
-        retired = live & ~still
-        th_out = theta + cfg.alpha
-        denom = reducer.psum(jnp.sum(th_out, -1, keepdims=True),
-                             "slab_theta_norm", compress=False)
-        theta_out = th_out / denom                                  # [B, Kl]
-        state = SlabState(word_rows=wid, counts=cnt, mu=mu, theta=theta,
-                          r_doc=r_doc, r_prev=r_prev, it=it, live=still)
+        with jax.named_scope("slab.retire"):
+            still = active_slots(r_doc, r_prev, it, live, tok_d)
+            retired = live & ~still
+            th_out = theta + cfg.alpha
+            denom = reducer.psum(jnp.sum(th_out, -1, keepdims=True),
+                                 "slab_theta_norm", compress=False)
+            theta_out = th_out / denom                              # [B, Kl]
+            state = SlabState(word_rows=wid, counts=cnt, mu=mu, theta=theta,
+                              r_doc=r_doc, r_prev=r_prev, it=it, live=still)
         return state, retired, theta_out, it, r_doc
 
     def step(phi_norm, state, refill_rows, refill_cnt, refill_slot,

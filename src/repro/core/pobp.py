@@ -571,39 +571,49 @@ def pobp_minibatch(
     phi_wire = (jnp.bfloat16 if cfg.phi_acc_dtype == "bfloat16" else None)
 
     # ---- lines 3-8: random init, local stats, first dense update ----
+    # Each phase runs under a jax.named_scope named after the CommMeter's
+    # phase vocabulary; the scope reaches every compiled instruction's
+    # op_name, so a device trace's ops can be charged to their phase
+    # (repro.obs.op_scopes).
     D, L = batch.word_ids.shape
-    u0 = init_field(key, D, L, cfg, Kl,
-                    data_reducer.shard_index() * D,
-                    model_reducer.shard_index() * Kl)
-    mu0 = u0 / model_reducer.psum(jnp.sum(u0, -1, keepdims=True), "model_norm",
-                                  compress=False)
-    delta_local0 = token_scatter_wk(batch.word_ids, batch.counts[..., None] * mu0, W)
-    phi_eff = phi_acc_wk + delta_local0          # local phi^0 (Fig. 4 line 5)
-    phi_tot = jnp.sum(phi_eff, axis=0)
+    with jax.named_scope("pobp.init"):
+        u0 = init_field(key, D, L, cfg, Kl,
+                        data_reducer.shard_index() * D,
+                        model_reducer.shard_index() * Kl)
+        mu0 = u0 / model_reducer.psum(jnp.sum(u0, -1, keepdims=True),
+                                      "model_norm", compress=False)
+        delta_local0 = token_scatter_wk(batch.word_ids,
+                                        batch.counts[..., None] * mu0, W)
+        phi_eff = phi_acc_wk + delta_local0      # local phi^0 (Fig. 4 line 5)
+        phi_tot = jnp.sum(phi_eff, axis=0)
     pallas_dense = cfg.impl == "pallas" and isinstance(model_reducer,
                                                        LocalReducer)
     if cfg.impl == "pallas" and not pallas_dense:
         note_bypass("dense_sweep", dict(D=D, L=L, Kl=Kl))
-    if pallas_dense:
-        # fused Pallas kernel (normalization in-kernel => K must be unsharded)
-        from repro.kernels.bp_update.ops import dense_sweep_pallas
-        mu1, r_wk_local = dense_sweep_pallas(batch, mu0, phi_eff, phi_tot, cfg,
-                                             layout, wbeta=wbeta)
-    else:
-        mu1, r_wk_local = dense_sweep(batch, mu0, phi_eff, phi_tot, cfg,
-                                      model_reducer, wbeta=wbeta)
+    with jax.named_scope("pobp.dense_sweep"):
+        if pallas_dense:
+            # fused Pallas kernel (normalization in-kernel => K unsharded)
+            from repro.kernels.bp_update.ops import dense_sweep_pallas
+            mu1, r_wk_local = dense_sweep_pallas(batch, mu0, phi_eff, phi_tot,
+                                                 cfg, layout, wbeta=wbeta)
+        else:
+            mu1, r_wk_local = dense_sweep(batch, mu0, phi_eff, phi_tot, cfg,
+                                          model_reducer, wbeta=wbeta)
 
     # ---- lines 9-10: dense synchronization of phi and r ----
-    delta_glob = data_reducer.psum(
-        token_scatter_wk(batch.word_ids, batch.counts[..., None] * mu1, W),
-        "dense", w_rows=W, dtype=phi_wire)
-    phi_eff = phi_acc_wk + delta_glob
-    phi_tot = jnp.sum(phi_eff, axis=0)
-    r_glob = data_reducer.psum(r_wk_local, "dense", w_rows=W,
-                               dtype=phi_wire)
-    theta = jnp.einsum("dl,dlk->dk", batch.counts, mu1, precision=HIGHEST)
-    r_w = model_reducer.psum(jnp.sum(r_glob, axis=1), "model_rw",
-                             compress=False, w_rows=W)
+    with jax.named_scope("pobp.dense_sync"):
+        delta_glob = data_reducer.psum(
+            token_scatter_wk(batch.word_ids,
+                             batch.counts[..., None] * mu1, W),
+            "dense", w_rows=W, dtype=phi_wire)
+        phi_eff = phi_acc_wk + delta_glob
+        phi_tot = jnp.sum(phi_eff, axis=0)
+        r_glob = data_reducer.psum(r_wk_local, "dense", w_rows=W,
+                                   dtype=phi_wire)
+        theta = jnp.einsum("dl,dlk->dk", batch.counts, mu1,
+                           precision=HIGHEST)
+        r_w = model_reducer.psum(jnp.sum(r_glob, axis=1), "model_rw",
+                                 compress=False, w_rows=W)
 
     if sync_mode == "power":
         # Token-major persistent inner loop (DESIGN.md §2): messages are
@@ -633,30 +643,35 @@ def pobp_minibatch(
             # Live-W runs mask guard rows out and cap the selection at the
             # live lambda_w fraction; dead slots point at the first guard
             # row, whose packed values are exact zeros (§12).
-            if live_w is None:
-                sel_w = pw.select_power_words(r_w_c, P)
-            else:
-                sel_w = pw.select_power_words_live(r_w_c, P, live_w,
-                                                   cfg.lambda_w)
-            sel_k = pw.select_power_topics(r_glob, sel_w, Pk)
-            mu_t, theta, d_phi_pack, r_pack = sweep_fn(
-                layout, mu_t, theta, phi_eff, phi_tot, sel_w, sel_k, cfg,
-                wbeta=wbeta)
+            with jax.named_scope("pobp.select"):
+                if live_w is None:
+                    sel_w = pw.select_power_words(r_w_c, P)
+                else:
+                    sel_w = pw.select_power_words_live(r_w_c, P, live_w,
+                                                       cfg.lambda_w)
+                sel_k = pw.select_power_topics(r_glob, sel_w, Pk)
+            with jax.named_scope("pobp.selective_sweep"):
+                mu_t, theta, d_phi_pack, r_pack = sweep_fn(
+                    layout, mu_t, theta, phi_eff, phi_tot, sel_w, sel_k, cfg,
+                    wbeta=wbeta)
             # lines 23-24: communicate only the power submatrices (the [P,
             # Pk] buffers scale with W through P = lambda_w*W: live-W
             # accounting bills only the live fraction of their rows)
-            d_phi_pack = data_reducer.psum(d_phi_pack, "power", w_rows=W,
+            with jax.named_scope("pobp.power_sync"):
+                d_phi_pack = data_reducer.psum(d_phi_pack, "power", w_rows=W,
+                                               dtype=phi_wire)
+                r_pack = data_reducer.psum(r_pack, "power", w_rows=W,
                                            dtype=phi_wire)
-            r_pack = data_reducer.psum(r_pack, "power", w_rows=W,
-                                       dtype=phi_wire)
             # packed-carry refresh: O(P*Pk) state updates, Eq. 9
-            rw_delta = packed_rw_delta(r_glob, sel_w, sel_k, r_pack)
-            phi_eff = phi_scatter(phi_eff, sel_w, sel_k, d_phi_pack)
-            phi_tot = phi_tot + jnp.zeros_like(phi_tot).at[sel_k].add(d_phi_pack)
-            r_glob = pw.scatter_set_rows(r_glob, sel_w, sel_k, r_pack)
-            rw_delta = model_reducer.psum(rw_delta, "model_rw_loop",
-                                          compress=False, w_rows=W)
-            r_w_c = r_w_c.at[sel_w].add(rw_delta)
+            with jax.named_scope("pobp.scatter"):
+                rw_delta = packed_rw_delta(r_glob, sel_w, sel_k, r_pack)
+                phi_eff = phi_scatter(phi_eff, sel_w, sel_k, d_phi_pack)
+                phi_tot = phi_tot + jnp.zeros_like(phi_tot).at[sel_k].add(
+                    d_phi_pack)
+                r_glob = pw.scatter_set_rows(r_glob, sel_w, sel_k, r_pack)
+                rw_delta = model_reducer.psum(rw_delta, "model_rw_loop",
+                                              compress=False, w_rows=W)
+                r_w_c = r_w_c.at[sel_w].add(rw_delta)
             return (mu_t, theta, phi_eff, phi_tot, r_glob, r_w_c, t + 1)
 
         mu_t, theta, phi_eff, phi_tot, r_glob, r_w, t = jax.lax.while_loop(
@@ -694,17 +709,20 @@ def pobp_minibatch(
         raise ValueError(f"unknown sync_mode: {sync_mode}")
 
     # ---- Eq. (11): accumulate this batch's synchronized gradient ----
-    if decay is None:
-        phi_acc_new = phi_acc_wk + delta_weight * (phi_eff - phi_acc_wk)
-    else:
-        # RM decay (§14): retain (1 - rho_m) of the historical statistic.
-        # phi_eff - phi_acc_wk is exactly this batch's synchronized Delta,
-        # so the expression below is the decayed Eq. 11 and reduces to the
-        # branch above at decay == 1.  The full-statistic touch is billed
-        # once per mini-batch (not a psum — decay is shard-local and
-        # identical everywhere, but it is a real [W, Kl] HBM pass).
-        data_reducer.bill(phi_acc_wk, "decay", w_rows=W)
-        phi_acc_new = decay * phi_acc_wk + delta_weight * (phi_eff - phi_acc_wk)
+    with jax.named_scope("pobp.accumulate"):
+        if decay is None:
+            phi_acc_new = phi_acc_wk + delta_weight * (phi_eff - phi_acc_wk)
+        else:
+            # RM decay (§14): retain (1 - rho_m) of the historical
+            # statistic.  phi_eff - phi_acc_wk is exactly this batch's
+            # synchronized Delta, so the expression below is the decayed
+            # Eq. 11 and reduces to the branch above at decay == 1.  The
+            # full-statistic touch is billed once per mini-batch (not a
+            # psum — decay is shard-local and identical everywhere, but it
+            # is a real [W, Kl] HBM pass).
+            data_reducer.bill(phi_acc_wk, "decay", w_rows=W)
+            phi_acc_new = (decay * phi_acc_wk
+                           + delta_weight * (phi_eff - phi_acc_wk))
     return MinibatchResult(phi_acc_new=phi_acc_new, iters=t,
                            mean_r=mean_residual(r_w, total_tokens),
                            mu=mu, theta=theta)

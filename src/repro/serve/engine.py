@@ -42,12 +42,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import infer, perplexity
 from repro.core.types import LDAConfig
 from repro.data.batching import bucket_len, docs_to_padded, slab_refill
 from repro.serve.cache import ThetaCache, doc_digest
 
 _EMPTY_DOC = (np.zeros(1, np.int32), np.zeros(1, np.float32))
+# stats() reads latency percentiles over the most recent requests only
+LATENCY_WINDOW = 65536
 
 
 @dataclasses.dataclass
@@ -308,7 +311,7 @@ class FoldInEngine:
         self._next_id = 0
         self._dispatches = 0
         self._iters_sum = 0
-        self._latencies: List[float] = []
+        self._latencies: "deque[float]" = deque(maxlen=LATENCY_WINDOW)
         self._served = 0
         self._oov_tokens = 0.0
         self._total_tokens = 0.0
@@ -588,6 +591,7 @@ class _StepOut:
     iters: jnp.ndarray                 # [B] int32
     r_doc: jnp.ndarray                 # [B] f32
     phi_version: int
+    t_dispatch_ns: int                 # host clock of the dispatch (obs)
 
 
 class SlabEngine:
@@ -701,7 +705,7 @@ class SlabEngine:
         self._warm_iters = 0
         self._cold_iters = 0
         self._billed_bytes = 0.0
-        self._latencies: List[float] = []
+        self._latencies: "deque[float]" = deque(maxlen=LATENCY_WINDOW)
         self._oov_tokens = 0.0
         self._total_tokens = 0.0
         self._t_first: Optional[float] = None
@@ -763,6 +767,11 @@ class SlabEngine:
         typed ``Shed`` instead of queueing (DESIGN.md §17); a document
         with non-finite counts retires immediately with
         ``error='nonfinite_input'`` instead of poisoning the slab."""
+        with obs.span("slab.submit"):
+            return self._submit(doc, req_id, tenant)
+
+    def _submit(self, doc, req_id, tenant) -> "int | Shed":
+        t_ns = time.perf_counter_ns()
         if req_id is None:
             req_id = self._next_id
         self._next_id = max(self._next_id, req_id) + 1
@@ -818,6 +827,7 @@ class SlabEngine:
                             slo_s=self.admission_slo_s,
                             queue_depth=len(self._queue), tenant=tenant)
         self._queue.append((req, rows, counts))
+        obs.stamp("submit", req_id, t_ns)
         return req_id
 
     def _est_wait_s(self) -> float:
@@ -850,28 +860,32 @@ class SlabEngine:
         while the host runs ahead.  Returns how many documents were
         harvested (possibly from earlier steps)."""
         t0 = time.time()
-        n_take = min(len(self._queue), len(self._free), self._refill_cap)
-        take = [self._queue.popleft() for _ in range(n_take)]
-        slot_ids = [self._free.popleft() for _ in range(n_take)]
-        wid, cnt, slot, _ = slab_refill(
-            [(rows, counts) for _, rows, counts in take], slot_ids,
-            capacity=self._refill_cap, slot_len=self.slot_len,
-            pad_slot=self.slots)
-        warm = np.zeros((self._refill_cap, self._K), np.float32)
-        wmask = np.zeros((self._refill_cap,), bool)
-        for i, (req, _, _) in enumerate(take):
-            if req.warm is not None:
-                warm[i] = req.warm
-                wmask[i] = True
-        for s, (req, _, _) in zip(slot_ids, take):
-            self._slot_req[s] = req
-        self._occ_sum += self.live_slots()
-        self._key, sub = jax.random.split(self._key)
-        self._state, retired, theta_out, iters, r_doc = self._step(
-            self._phi, self._state, wid, cnt, slot, warm, wmask, sub)
+        with obs.span("slab.refill"):
+            n_take = min(len(self._queue), len(self._free), self._refill_cap)
+            take = [self._queue.popleft() for _ in range(n_take)]
+            slot_ids = [self._free.popleft() for _ in range(n_take)]
+            wid, cnt, slot, _ = slab_refill(
+                [(rows, counts) for _, rows, counts in take], slot_ids,
+                capacity=self._refill_cap, slot_len=self.slot_len,
+                pad_slot=self.slots)
+            warm = np.zeros((self._refill_cap, self._K), np.float32)
+            wmask = np.zeros((self._refill_cap,), bool)
+            for i, (req, _, _) in enumerate(take):
+                if req.warm is not None:
+                    warm[i] = req.warm
+                    wmask[i] = True
+            for s, (req, _, _) in zip(slot_ids, take):
+                self._slot_req[s] = req
+            self._occ_sum += self.live_slots()
+        with obs.span("slab.dispatch") as sp:
+            self._key, sub = jax.random.split(self._key)
+            self._state, retired, theta_out, iters, r_doc = self._step(
+                self._phi, self._state, wid, cnt, slot, warm, wmask, sub)
+        for req, _, _ in take:
+            obs.stamp("refill", req.req_id, sp.t0_ns)
         self._steps += 1
         self._pending.append(_StepOut(retired, theta_out, iters, r_doc,
-                                      self.phi_version))
+                                      self.phi_version, sp.t0_ns))
         n = self._harvest(block=len(self._pending) > self._pipeline)
         dt = time.time() - t0
         self._step_ema_s = (dt if self._step_ema_s is None
@@ -894,13 +908,23 @@ class SlabEngine:
         return n
 
     def _materialize(self, out: _StepOut) -> int:
-        ret = np.asarray(out.retired)    # the (only) host sync point
+        with obs.span("slab.harvest.block"):
+            ret = np.asarray(out.retired)    # the (only) host sync point
         if not ret.any():
             return 0
-        th = np.asarray(out.theta)
-        itn = np.asarray(out.iters)
-        rn = np.asarray(out.r_doc)
+        with obs.span("slab.harvest.fetch"):
+            th = np.asarray(out.theta)
+            itn = np.asarray(out.iters)
+            rn = np.asarray(out.r_doc)
         t_done = time.time()
+        t_done_ns = time.perf_counter_ns()
+        with obs.span("slab.harvest.retire"):
+            return self._retire(out, ret, th, itn, rn, t_done, t_done_ns)
+
+    def _retire(self, out: _StepOut, ret, th, itn, rn, t_done: float,
+                t_done_ns: int) -> int:
+        """Hand each document the step retired its theta, free its slot,
+        and bill and count it."""
         sweep_b, once_b = self._billing_rates()
         n = 0
         for s in np.nonzero(ret)[0]:
@@ -930,6 +954,8 @@ class SlabEngine:
                 oov_tokens=req.oov, phi_version=out.phi_version,
                 comm_bytes=bytes_d, cached=False, tenant=req.tenant,
                 error=None if finite else "nonfinite_theta"))
+            obs.stamp("retire_dispatch", req.req_id, out.t_dispatch_ns)
+            obs.stamp("done", req.req_id, t_done_ns)
             self._latencies.append(lat)
             self._iters_sum += doc_iters
             if req.warm is not None:
