@@ -12,11 +12,18 @@ TPU Pallas has no general dynamic gather, and Mosaic moves HBM data in
   - the *column* gather (power topics, per row): ``Pk`` compare-select
     passes over the [RB, K] row block — branch-free, exact, no MXU.
 
-The inverse scatter aliases the destination matrix in place and takes
-one selected row per grid step, rows sorted (ops.py) so that rows
-sharing a tile are consecutive steps: the tile stays resident in VMEM
-across them (the first visit adds to the fetched tile, later visits to
-the resident output block) and is written back once.
+The inverse scatter aliases the destination matrix in place and walks
+*tile visits*, one grid step each: a visit is an aligned 8-row tile and
+at most one selected row per sublane (``row % 8``), given as an [8, Pk]
+block of topic ids and one of values (ids outside [0, K) on sublanes
+with no row).  The step builds the tile's [8, K] delta with every
+sublane live, one compare-select-add over the tile per power-topic
+column (static lane slices, no loop-carried lane reduction), and adds
+it.  A row selected twice takes a second visit of its tile.  Visits of
+one tile are consecutive steps (ops.py lays them out from the sorted
+rows), so the tile stays resident in VMEM across them (the first visit
+fetches it, later ones add to the resident output block) and is written
+back once; a step with no row leaves its tile as it is.
 
 Rows in the matrix's last, partial tile (``row >= W8``, W8 = W rounded
 down to 8) are skipped here; the ops layer moves those few rows with XLA.
@@ -76,40 +83,26 @@ def _pack_kernel(sel_w_ref, sel_k_ref, *refs, n_tiles: int):
                                      jnp.zeros(sel.shape, jnp.float32))
 
 
-def _scatter_add_kernel(sel_w_ref, sel_k_ref, vals_ref, mat_ref, out_ref, *,
-                        n_sel: int, n_tiles: int):
-    p = pl.program_id(0)
-    row = sel_w_ref[p]
-    tile = _tile_of(row, n_tiles)
+def _scatter_add_kernel(visit_ref, ids_ref, vals_ref, mat_ref, out_ref):
+    v = pl.program_id(0)
+    code = visit_ref[v]
     first = jnp.logical_or(
-        p == 0, tile != _tile_of(sel_w_ref[jnp.maximum(p - 1, 0)], n_tiles))
-    live = jnp.logical_and(p < n_sel, row < n_tiles * TILE)
-
-    # this step's sel_k / vals row out of the [RB, Pk] blocks
-    sel = _row_of(sel_k_ref[...].astype(jnp.float32), p % RB)   # [1, Pk]
-    vals = _row_of(vals_ref[...], p % RB)
-    shape = mat_ref.shape                                       # [8, K]
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, shape, 1
-                                      ).astype(jnp.float32)
-    on_row = jnp.logical_and(
-        jax.lax.broadcasted_iota(jnp.int32, shape, 0) == row - TILE * tile,
-        live)
-    lane = jax.lax.broadcasted_iota(jnp.int32, sel.shape, 1)
-
-    def body(q, acc):
-        hit = jnp.logical_and(on_row, iota_k == _column(sel, lane, q))
-        return acc + jnp.where(hit, _column(vals, lane, q), 0.0)
-
-    contrib = jax.lax.fori_loop(0, sel.shape[1], body,
-                                jnp.zeros(shape, jnp.float32))
+        v == 0, code // 2 != visit_ref[jnp.maximum(v - 1, 0)] // 2)
 
     @pl.when(first)
     def _fetched():
-        out_ref[...] = mat_ref[...] + contrib
+        out_ref[...] = mat_ref[...]
 
-    @pl.when(jnp.logical_not(first))
-    def _resident():
-        out_ref[...] += contrib
+    @pl.when(code % 2 == 1)
+    def _apply():
+        ids = ids_ref[...]                                      # [8, Pk]
+        vals = vals_ref[...]
+        iota_k = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+        delta = jnp.zeros(out_ref.shape, jnp.float32)           # [8, K]
+        for j in range(ids.shape[1]):
+            delta += jnp.where(iota_k == ids[:, j:j + 1], vals[:, j:j + 1],
+                               0.0)
+        out_ref[...] += delta
 
 
 def pack_rows_pallas(mat_wk: jnp.ndarray, sel_w: jnp.ndarray,
@@ -138,27 +131,29 @@ def pack_rows_pallas(mat_wk: jnp.ndarray, sel_w: jnp.ndarray,
     )(sel_w, sel_k, *([mat_wk] * RB))
 
 
-def scatter_add_rows_pallas(mat_wk: jnp.ndarray, sel_w: jnp.ndarray,
-                            sel_k: jnp.ndarray, vals: jnp.ndarray,
-                            n_sel: int) -> jnp.ndarray:
-    """mat[sel_w[p], sel_k[p, j]] += vals[p, j], in place (aliased), for
-    rows below W8.  ``sel_w`` must be sorted ascending (ops.py sorts)
-    and padded to a multiple of RB; ``n_sel`` counts the real rows."""
-    P, Pk = sel_k.shape
+def scatter_add_rows_pallas(mat_wk: jnp.ndarray, visit: jnp.ndarray,
+                            ids: jnp.ndarray, vals: jnp.ndarray
+                            ) -> jnp.ndarray:
+    """Apply tile visits to ``mat_wk`` [W, K] in place (aliased).
+
+    Visit ``v`` adds ``vals[8v + s, j]`` at row ``8 * tile + s``, column
+    ``ids[8v + s, j]`` (ids outside [0, K) add nothing), where ``visit[v]
+    = 2 * tile + live``; a visit that is not live leaves its tile as it
+    is.  Visits of one tile must be consecutive (ops.py orders them)."""
+    V = visit.shape[0]
+    Pk = ids.shape[1]
     W, K = mat_wk.shape
-    n_tiles = W // TILE
-    tab = pl.BlockSpec((RB, Pk), lambda p, sel_w: (p // RB, 0))
-    tile = pl.BlockSpec((TILE, K),
-                        lambda p, sel_w: (_tile_of(sel_w[p], n_tiles), 0))
+    tab = pl.BlockSpec((TILE, Pk), lambda v, visit: (v, 0))
+    tile = pl.BlockSpec((TILE, K), lambda v, visit: (visit[v] // 2, 0))
     return pl.pallas_call(
-        functools.partial(_scatter_add_kernel, n_sel=n_sel, n_tiles=n_tiles),
+        _scatter_add_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(P,),
+            num_scalar_prefetch=1, grid=(V,),
             in_specs=[tab, tab, tile], out_specs=tile),
         out_shape=jax.ShapeDtypeStruct((W, K), jnp.float32),
-        # input indices count the scalar-prefetch operand: sel_w=0, sel_k=1,
+        # input indices count the scalar-prefetch operand: visit=0, ids=1,
         # vals=2, mat=3 -> alias mat onto the (sole) output.
         input_output_aliases={3: 0},
         compiler_params=K_.compiler_params(K_.vmem_budget()),
         interpret=K_.INTERPRET,
-    )(sel_w, sel_k, vals, mat_wk)
+    )(visit, ids, vals, mat_wk)

@@ -1,6 +1,8 @@
 """Per-kernel validation: shape/dtype sweeps + hypothesis properties,
 always against the pure-jnp ref.py oracle (interpret mode on CPU)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,13 +12,14 @@ pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import LDAConfig, MiniBatch
+from repro.core import power as pw
 from repro.core.pobp import dense_sweep
 from repro.core.sync import LocalReducer
 from repro.kernels.bp_update.kernel import bp_update_tokens, token_tile
 from repro.kernels.bp_update.ops import dense_sweep_pallas
 from repro.kernels.bp_update.ref import bp_update_tokens_ref
 from repro.kernels.power_pack import ops as pp_ops
-from repro.kernels.power_pack.ref import pack_rows_ref, scatter_add_rows_ref
+from repro.kernels.power_pack.ref import pack_rows_ref
 
 
 def _rand_inputs(key, T, K, dtype=jnp.float32):
@@ -87,22 +90,68 @@ def test_dense_sweep_pallas_matches_jnp_sweep():
 
 # ------------------------------------------------------------ power_pack
 
-@pytest.mark.parametrize("W,K,P,Pk", [(64, 32, 8, 4), (128, 256, 16, 50),
-                                      (500, 96, 50, 10), (32, 130, 4, 130)])
-def test_power_pack_shape_sweep(W, K, P, Pk):
+def _pp(W, K, P, Pk, rows=None, guard=None, id=None):
+    """One power_pack case: random distinct rows (rows=None) or the given
+    ones; entries on the ``guard`` row carry zeros."""
+    return pytest.param(W, K, P if rows is None else len(rows), Pk, rows,
+                        guard, id=id or f"{W}-{K}-{P}-{Pk}")
+
+
+@pytest.mark.parametrize("W,K,P,Pk,rows,guard", [
+    _pp(64, 32, 8, 4), _pp(128, 256, 16, 50), _pp(500, 96, 50, 10),
+    _pp(32, 130, 4, 130),
+    # every row of a tile, and of the tiles beside it (given unsorted)
+    _pp(64, 32, 0, 4, list(range(31, 7, -1)), id="full-adjacent-tiles"),
+    _pp(64, 32, 0, 4, [9 * t for t in range(8)], id="one-row-per-tile"),
+    # W % 8 = 3 and 4, with rows of the partial last tile and without
+    _pp(67, 40, 0, 6, [66, 64, 1, 30, 65, 12], id="w8r3-tail"),
+    _pp(67, 40, 0, 6, [0, 7, 8, 40, 63], id="w8r3-no-tail"),
+    _pp(100, 128, 0, 16, [97, 5, 99, 96, 40, 98, 95, 8, 9], id="w8r4-tail"),
+    _pp(100, 128, 0, 16, [95, 5, 88, 40, 8, 9, 0], id="w8r4-no-tail"),
+    _pp(67, 16, 0, 16, [66, 3, 64, 20], id="pk-eq-k-tail"),
+    _pp(131, 24, 0, 8, list(range(130, 0, -13)), id="descending"),
+    # repeated rows (body and tail) whose values must all add up
+    _pp(67, 32, 0, 5, [3, 17, 3, 66, 40, 17, 3, 66, 64, 65, 66],
+        id="repeated"),
+    # the capacity ladder: dead slots all on the first guard row, zeros
+    _pp(72, 32, 0, 4, [7, 49, 12, 30, 0] + [50] * 7, guard=50,
+        id="guard-row"),
+    _pp(67, 32, 0, 4, [7, 61, 12, 30, 64, 2] + [65] * 14, guard=65,
+        id="guard-row-in-tail"),
+])
+def test_power_pack_shape_sweep(W, K, P, Pk, rows, guard):
     rng = np.random.default_rng(W + K)
     mat = jnp.asarray(rng.normal(size=(W, K)).astype(np.float32))
-    sel_w = jnp.asarray(rng.choice(W, P, replace=False).astype(np.int32))
+    sel_w = (rng.choice(W, P, replace=False) if rows is None
+             else np.asarray(rows))
     sel_k = jnp.asarray(np.stack([rng.choice(K, Pk, replace=False)
                                   for _ in range(P)]).astype(np.int32))
-    vals = jnp.asarray(rng.normal(size=(P, Pk)).astype(np.float32))
+    vals = rng.normal(size=(P, Pk)).astype(np.float32)
+    vals[sel_w == guard] = 0.0
+    sel_w, vals = jnp.asarray(sel_w.astype(np.int32)), jnp.asarray(vals)
     np.testing.assert_allclose(np.asarray(pp_ops.pack_rows(mat, sel_w, sel_k)),
                                np.asarray(pack_rows_ref(mat, sel_w, sel_k)),
                                rtol=1e-6)
-    np.testing.assert_allclose(
+    np.testing.assert_array_equal(
         np.asarray(pp_ops.scatter_add_rows(mat, sel_w, sel_k, vals)),
-        np.asarray(scatter_add_rows_ref(mat, sel_w, sel_k, vals)),
-        rtol=1e-5, atol=1e-5)
+        np.asarray(jax.jit(pw.scatter_add_rows)(mat, sel_w, sel_k, vals)))
+
+
+def test_power_pack_scatter_moves_tail_entries_alone():
+    """At W % 8 != 0 no XLA scatter into the [W, K] matrix takes more than
+    7 * Pk updates: the partial last tile's rows get only their entries."""
+    W, K, P, Pk = 67, 16, 12, 5
+    text = jax.jit(pp_ops.scatter_add_rows).lower(
+        jax.ShapeDtypeStruct((W, K), jnp.float32),
+        jax.ShapeDtypeStruct((P,), jnp.int32),
+        jax.ShapeDtypeStruct((P, Pk), jnp.int32),
+        jax.ShapeDtypeStruct((P, Pk), jnp.float32)).as_text()
+    sigs = re.findall(r'"stablehlo\.scatter"\(.*?\}\) : \(tensor<([^>]*)>, '
+                      r'tensor<[^>]*>, tensor<([^>]*)>\)', text, re.S)
+    assert sigs, "no scatter found in the module"
+    updates = [int(np.prod([int(d) for d in u.split("x")[:-1]]))
+               for op, u in sigs if op == f"{W}x{K}xf32"]
+    assert all(n <= 7 * Pk for n in updates), updates
 
 
 @settings(max_examples=20, deadline=None)

@@ -1,6 +1,7 @@
 """Every Pallas kernel of the main path, compiled by Mosaic for one
 described TPU v5e chip at NYTimes width (W=102,660, K=1000, lambda_W=0.1
-so P=10,266 power words; Pk=50), with no chip attached.
+so P=10,266 power words; Pk=50), with no chip attached; ``power_pack``
+also at PubMed width (W=141,043, K=2000, P=14,104, Pk=100).
 
 A compile that passes here is not a chip run: nothing executes.  It is
 what the chip's compiler accepts — block shapes, VMEM limits, DMA
@@ -138,10 +139,21 @@ def test_nytimes_training_sweep_dispatches_to_xla():
     assert sd.resolve_fold_in(K, D) == "dense_layout"
 
 
-def test_power_pack_gather_and_scatter(one_chip, mosaic):
+@pytest.mark.parametrize("w,k,p,pk", [(W, K, P, PK),
+                                      (141_043, 2000, 14_104, 100)],
+                         ids=["nytimes", "pubmed"])
+def test_power_pack_gather_and_scatter(one_chip, mosaic, w, k, p, pk):
+    """The packed gather and the tile-visit scatter at NYTimes and PubMed
+    width; the scatter's kernel keeps the op name the benchmark's
+    ``power_pack.roofline`` reader looks for."""
+    import re
     from repro.kernels.power_pack.ops import pack_rows, scatter_add_rows
-    mat = _s(one_chip, (W, K))
-    sel_w = _s(one_chip, (P,), jnp.int32)
-    sel_k = _s(one_chip, (P, PK), jnp.int32)
+    mat = _s(one_chip, (w, k))
+    sel_w = _s(one_chip, (p,), jnp.int32)
+    sel_k = _s(one_chip, (p, pk), jnp.int32)
     _compile(pack_rows, mat, sel_w, sel_k)
-    _compile(scatter_add_rows, mat, sel_w, sel_k, _s(one_chip, (P, PK)))
+    text = _compile(scatter_add_rows, mat, sel_w, sel_k, _s(one_chip, (p, pk)))
+    calls = re.findall(
+        r'%([\w.]+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert len(calls) == 1 and re.fullmatch(r"scatter_add_rows\.\d+",
+                                            calls[0]), calls
