@@ -289,7 +289,7 @@ def _selective_sweep_packed(
 
 def _selective_sweep_dense_layout(
     layout: TokenLayout, mu_t, theta, phi_eff_wk, phi_tot, sel_w, sel_k,
-    cfg: LDAConfig, wbeta=None,
+    cfg: LDAConfig, wbeta=None, model_reducer: Optional[Reducer] = None,
 ):
     """One-pass dense-layout formulation: masked [T, K] update, no chain.
 
@@ -313,6 +313,13 @@ def _selective_sweep_dense_layout(
     the imaginary lane — halves the serialized scatter elements) followed
     by an O(P*Pk) column pack.  Same contract and packed outputs as
     `_selective_sweep_packed`.
+
+    ``model_reducer`` (topic-sharded runs only): ``sel_k`` holds this
+    shard's local ids and the sentinel ``Kl`` for topics other shards own
+    (`power.select_power_topics_merged`), so the selected mass and the
+    denominator are psum'd over the model axis before the renormalization
+    (exact over the global selection), and the sentinel's packed slots
+    read 0.
     """
     P, Pk = sel_k.shape
     Kl = mu_t.shape[1]
@@ -341,15 +348,23 @@ def _selective_sweep_dense_layout(
     pt = phi_tot[None, None, :] - self_c + wb
     u = jnp.where(selp, th * ph / pt, 0.0)
     mass = jnp.sum(jnp.where(selp, mu3, 0.0), -1, keepdims=True)
-    denom = jnp.maximum(jnp.sum(u, -1, keepdims=True), 1e-30)
+    denom = jnp.sum(u, -1, keepdims=True)
+    fill = {}
+    if model_reducer is not None:
+        with jax.named_scope("pobp.model_norm"):
+            both = model_reducer.psum(jnp.concatenate([mass, denom], -1),
+                                      "model_norm_loop", compress=False)
+            mass, denom = both[..., :1], both[..., 1:]
+        fill = dict(mode="fill", fill_value=0)
+    denom = jnp.maximum(denom, 1e-30)
     mu_new = jnp.where(selp, u * (mass / denom), mu3)
     theta_new = jnp.einsum("dl,dlk->dk", counts2, mu_new, precision=HIGHEST)
     cd = c3 * (mu_new - mu3)
     zc = jax.lax.complex(cd, jnp.abs(cd)).reshape(layout.num_slots, Kl)
     rows = jnp.zeros((P + 1, Kl), jnp.complex64).at[
         p_tok3.reshape(-1)].add(zc)
-    d_pack = jnp.take_along_axis(jnp.real(rows[:P]), sel_k, axis=1)
-    r_pack = jnp.take_along_axis(jnp.imag(rows[:P]), sel_k, axis=1)
+    d_pack = jnp.take_along_axis(jnp.real(rows[:P]), sel_k, axis=1, **fill)
+    r_pack = jnp.take_along_axis(jnp.imag(rows[:P]), sel_k, axis=1, **fill)
     return (mu_new.reshape(layout.num_slots, Kl),
             theta_new, d_pack.astype(mu_t.dtype), r_pack.astype(mu_t.dtype))
 
@@ -487,24 +502,61 @@ def selective_sweep_tokens_pallas(
 # the per-shard mini-batch routine (Fig. 4 body, one m)
 # --------------------------------------------------------------------------
 
+_FIELD_MIN, _FIELD_MAX = 0.01, 1.0
+
+
+@functools.partial(jax.jit, static_argnames=("L", "K", "kl"))
+def _field_columns(key: jax.Array, L: int, K: int, k0, kl: int
+                   ) -> jnp.ndarray:
+    """Columns [k0, k0 + kl) of ``jax.random.uniform(key, (L, K),
+    minval=0.01, maxval=1.0)``, bit for bit, without drawing the others.
+
+    Under ``jax_threefry_partitionable`` element (l, k) of that draw is
+    the threefry hash of the counter l*K + k under the key (the high
+    counter word is 0 while L*K < 2**32), turned into a float the way
+    `jax.random.uniform` does; a column block hashes its own counters.
+    ``key`` is a raw threefry key (uint32[2])."""
+    from jax.extend.random import threefry2x32_p
+    cols = jnp.asarray(k0, jnp.uint32) + jnp.arange(kl, dtype=jnp.uint32)
+    lo = jnp.arange(L, dtype=jnp.uint32)[:, None] * jnp.uint32(K) + cols
+    # every operand at one shape and, under vmap, one batching (the
+    # primitive's CPU lowering needs both): each depends on key and k0
+    lo = lo + key[0] * 0
+    zero = lo * 0
+    b1, b2 = threefry2x32_p.bind(key[0] + zero, key[1] + zero, zero, lo)
+    mant = jax.lax.shift_right_logical(b1 ^ b2, jnp.uint32(32 - 23))
+    f = jax.lax.bitcast_convert_type(mant | jnp.uint32(0x3F800000),
+                                     jnp.float32) - jnp.float32(1.0)
+    lo_v, hi_v = jnp.float32(_FIELD_MIN), jnp.float32(_FIELD_MAX)
+    return jnp.maximum(lo_v, f * (hi_v - lo_v) + lo_v)
+
+
 def init_field(key: jax.Array, D: int, L: int, cfg: LDAConfig, kl: int,
                doc0, k0) -> jnp.ndarray:
     """The random message field u0 [D, L, kl] of a shard whose documents
     start at global index ``doc0`` and whose topics start at ``k0``.
 
-    Each document draws its [Lpad, K] field from ``fold_in(key, global
-    doc index)`` at the full K and keeps this shard's topic columns, so
-    the trajectory does not depend on how documents and topics are laid
-    over shards or chips (an N-shard run and a 1-shard run start alike).
-    cfg.init_pad_len: the field is drawn at a fixed padded length and
-    sliced, so phi_acc is invariant to the L bucket this batch landed in
-    (shape-bucketed streaming; padding slots have zero counts).
+    Each document's field is its [Lpad, K] draw from ``fold_in(key,
+    global doc index)`` at the full K, restricted to this shard's topic
+    columns, so the trajectory does not depend on how documents and
+    topics are laid over shards or chips (an N-shard run and a 1-shard
+    run start alike).  A topic shard hashes only its own columns
+    (`_field_columns`): the full draw would be a [D, L, K] temporary on
+    every chip.  cfg.init_pad_len: the field is drawn at a fixed padded
+    length and sliced, so phi_acc is invariant to the L bucket this batch
+    landed in (shape-bucketed streaming; padding slots have zero counts);
+    a row's counters do not depend on Lpad.
     """
     K = cfg.num_topics
+    docs = doc0 + jnp.arange(D, dtype=jnp.int32)
+    if (kl != K and key.dtype == jnp.uint32 and key.shape == (2,)
+            and jax.config.jax_threefry_partitionable and L * K < 2**32):
+        return jax.vmap(lambda d: _field_columns(
+            jax.random.fold_in(key, d), L=L, K=K, k0=k0, kl=kl))(docs)
     Lpad = L if cfg.init_pad_len is None else max(cfg.init_pad_len, L)
     u = jax.vmap(lambda d: jax.random.uniform(
-        jax.random.fold_in(key, d), (Lpad, K), minval=0.01, maxval=1.0))(
-            doc0 + jnp.arange(D, dtype=jnp.int32))[:, :L]
+        jax.random.fold_in(key, d), (Lpad, K), minval=_FIELD_MIN,
+        maxval=_FIELD_MAX))(docs)[:, :L]
     if kl != K:
         u = jax.lax.dynamic_slice_in_dim(u, k0, kl, axis=2)
     return u
@@ -558,9 +610,13 @@ def pobp_minibatch(
     statistic once per mini-batch, billed to the meter's ``decay`` phase.
     """
     model_reducer = model_reducer or LocalReducer(meter=data_reducer.meter)
+    # topics sharded over the model axis: power topics are selected over
+    # every shard's columns (an exact merge) and renormalized over them
+    topic_sharded = not isinstance(model_reducer, LocalReducer)
     W = cfg.vocab_size
     Kl = phi_acc_wk.shape[1]
-    P, Pk = cfg.num_power_words, min(cfg.num_power_topics, Kl)
+    P = cfg.num_power_words
+    Pk = min(cfg.num_power_topics, cfg.num_topics if topic_sharded else Kl)
     wbeta = (None if live_w is None
              else jnp.asarray(live_w, jnp.float32) * cfg.beta)
     layout = batch.token_layout()    # persistent token-major view (§2)
@@ -628,6 +684,19 @@ def pobp_minibatch(
         else:
             sweep_fn = selective_sweep_tokens
             phi_scatter = pw.scatter_add_rows
+        if topic_sharded:
+            # only the XLA dense-layout formulation renormalizes across
+            # topic shards; the kernels and the packed stream normalize
+            # over the shard's own columns
+            if cfg.sweep_policy == "packed":
+                raise ValueError(
+                    "sweep_policy='packed' cannot run with topics sharded "
+                    "over the model axis: use 'auto' or 'dense_layout'")
+            if cfg.impl == "pallas":
+                note_bypass("selective_sweep",
+                            dict(T=layout.num_slots, Kl=Kl, Pk=Pk, P=P))
+            sweep_fn = functools.partial(_selective_sweep_dense_layout,
+                                         model_reducer=model_reducer)
         carry0 = (mu1.reshape(layout.num_slots, Kl), theta, phi_eff, phi_tot,
                   r_glob, r_w, jnp.asarray(1, jnp.int32))
 
@@ -649,7 +718,11 @@ def pobp_minibatch(
                 else:
                     sel_w = pw.select_power_words_live(r_w_c, P, live_w,
                                                        cfg.lambda_w)
-                sel_k = pw.select_power_topics(r_glob, sel_w, Pk)
+                if topic_sharded:
+                    sel_k = pw.select_power_topics_merged(
+                        r_glob, sel_w, Pk, model_reducer)
+                else:
+                    sel_k = pw.select_power_topics(r_glob, sel_w, Pk)
             with jax.named_scope("pobp.selective_sweep"):
                 mu_t, theta, d_phi_pack, r_pack = sweep_fn(
                     layout, mu_t, theta, phi_eff, phi_tot, sel_w, sel_k, cfg,
@@ -664,7 +737,8 @@ def pobp_minibatch(
                                            dtype=phi_wire)
             # packed-carry refresh: O(P*Pk) state updates, Eq. 9
             with jax.named_scope("pobp.scatter"):
-                rw_delta = packed_rw_delta(r_glob, sel_w, sel_k, r_pack)
+                rw_delta = packed_rw_delta(r_glob, sel_w, sel_k, r_pack,
+                                           sentinel=topic_sharded)
                 phi_eff = phi_scatter(phi_eff, sel_w, sel_k, d_phi_pack)
                 phi_tot = phi_tot + jnp.zeros_like(phi_tot).at[sel_k].add(
                     d_phi_pack)

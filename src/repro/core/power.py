@@ -10,6 +10,9 @@ Because selection is computed from the *synchronized* residual (Eq. 9),
 every shard computes identical indices — no index traffic is needed,
 only the packed [P, Pk] value tensor crosses the interconnect.  This is
 the property that makes the paper's scheme XLA/TPU-native (DESIGN.md §2).
+Topics sharded over the model axis are the exception: each shard holds
+some of every row's columns, so the shards merge their candidates
+(`select_power_topics_merged`).
 """
 
 from __future__ import annotations
@@ -62,13 +65,49 @@ def select_power_topics(r_wk: jnp.ndarray, word_idx: jnp.ndarray,
                         num_power_topics: int) -> jnp.ndarray:
     """Per power word, top-`num_power_topics` topic indices (Fig. 4 lines 13/28).
 
-    r_wk: [W, K] synchronized residual matrix (local K-shard when the topic
-    axis is model-sharded — see DESIGN.md §2 on the per-shard variant).
-    Returns [P, Pk] int32.
+    r_wk: [W, K] synchronized residual matrix over every topic (a
+    topic-sharded run merges its shards' candidates instead:
+    `select_power_topics_merged`).  Returns [P, Pk] int32.
     """
     rows = jnp.take(r_wk, word_idx, axis=0)          # [P, K]
     _, idx = jax.lax.top_k(rows, num_power_topics)   # [P, Pk]
     return idx.astype(jnp.int32)
+
+
+def select_power_topics_merged(r_wk: jnp.ndarray, word_idx: jnp.ndarray,
+                               num_power_topics: int,
+                               model_reducer) -> jnp.ndarray:
+    """`select_power_topics` over the whole topic axis when ``r_wk`` is
+    this shard's [W, Kl] column block of a topic-sharded residual.
+
+    Each shard takes its local top candidates of every power word's row;
+    the shards all-gather the candidates' values and global ids over the
+    model axis (in shard order) and take the top `num_power_topics` of
+    them.  The gathered list holds equal values in ascending global id
+    (``top_k`` puts the lower index first, and shards hold ascending
+    column ranges), so the merge picks the same topics in the same order
+    as ``top_k`` over the unsharded row, ties included.  Every global
+    top-Pk topic is in its own shard's local top-Pk, so nothing is lost.
+
+    Returns [P, Pk] int32 *local* ids: the shard's own members of the
+    selection, and the sentinel ``Kl`` (one past its columns) in every
+    slot another shard owns.  Scatters drop the sentinel (out of
+    bounds); gathers at it must fill (``mode="fill"``).
+    """
+    Kl = r_wk.shape[1]
+    P = word_idx.shape[0]
+    rows = jnp.take(r_wk, word_idx, axis=0)                      # [P, Kl]
+    vals, idx = jax.lax.top_k(rows, min(num_power_topics, Kl))
+    k0 = model_reducer.shard_index() * Kl
+    with jax.named_scope("pobp.topic_merge"):
+        g_vals = model_reducer.all_gather(vals, "topic_merge")   # [S, P, c]
+        g_ids = model_reducer.all_gather(idx.astype(jnp.int32) + k0,
+                                         "topic_merge")
+        cand_v = jnp.moveaxis(g_vals, 0, 1).reshape(P, -1)
+        cand_i = jnp.moveaxis(g_ids, 0, 1).reshape(P, -1)
+        _, pos = jax.lax.top_k(cand_v, num_power_topics)
+        local = jnp.take_along_axis(cand_i, pos, axis=1) - k0
+    return jnp.where((local >= 0) & (local < Kl), local, Kl).astype(jnp.int32)
 
 
 def word_to_row(word_idx: jnp.ndarray, vocab_size: int) -> jnp.ndarray:

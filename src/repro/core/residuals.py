@@ -47,7 +47,8 @@ def mean_residual(r_w: jnp.ndarray, total_tokens: jnp.ndarray) -> jnp.ndarray:
 
 
 def packed_rw_delta(r_glob_wk: jnp.ndarray, sel_w: jnp.ndarray,
-                    sel_k: jnp.ndarray, r_pack_new: jnp.ndarray) -> jnp.ndarray:
+                    sel_k: jnp.ndarray, r_pack_new: jnp.ndarray,
+                    sentinel: bool = False) -> jnp.ndarray:
     """Per-power-word change of the word residual under a packed refresh.
 
     The selective iteration only rewrites r at the [P, Pk] power coordinates
@@ -59,8 +60,11 @@ def packed_rw_delta(r_glob_wk: jnp.ndarray, sel_w: jnp.ndarray,
     O(W*K) row reduction per iteration (DESIGN.md §2 packed-carry
     invariant).  Call BEFORE scattering r_pack_new into r_glob.
     Returns delta [P]; the caller adds it at rows sel_w (after the model
-    psum when the topic axis is sharded).
+    psum when the topic axis is sharded).  ``sentinel``: ``sel_k`` holds
+    out-of-range ids for topics another shard owns
+    (`power.select_power_topics_merged`); they read 0 and add nothing.
     """
     rows = jnp.take(r_glob_wk, sel_w, axis=0)
-    old = jnp.take_along_axis(rows, sel_k, axis=1)
+    fill = dict(mode="fill", fill_value=0) if sentinel else {}
+    old = jnp.take_along_axis(rows, sel_k, axis=1, **fill)
     return jnp.sum(r_pack_new - old, axis=1)

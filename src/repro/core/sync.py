@@ -33,7 +33,8 @@ AxisName = Union[str, Sequence[str]]
 # phases recorded once per *inner-loop iteration* (their psums live in
 # trace-once while bodies — core/pobp.py names every in-body psum with a
 # distinct loop phase); everything else is a once-per-mini-batch payload.
-_BASE_LOOP_PHASES = ("power", "dense_loop", "model_rw_loop", "model_norm_loop")
+_BASE_LOOP_PHASES = ("power", "dense_loop", "model_rw_loop", "model_norm_loop",
+                     "topic_merge")
 # the parameter-server reducer splits every vocabulary-proportional wire
 # payload into a ``.push`` and a ``.pull`` leg (see ``PSReducer``); the
 # loop-phase set covers both so ``per_minibatch_bytes`` stays correct
@@ -226,6 +227,16 @@ class Reducer:
         """This shard's position along the reduced axis (0 unsharded)."""
         return jnp.zeros((), jnp.int32)
 
+    def _gather(self, x: jnp.ndarray) -> jnp.ndarray:
+        raise NotImplementedError
+
+    def all_gather(self, x: jnp.ndarray, phase: str) -> jnp.ndarray:
+        """Every shard's `x`, stacked in shard order along a new leading
+        axis; billed at the gathered size (what each shard receives)."""
+        out = self._gather(x)
+        self.meter.record(phase, out)
+        return out
+
     def bill(self, x: jnp.ndarray, phase: str,
              w_rows: Optional[int] = None) -> jnp.ndarray:
         """Record a *local* full-statistic touch without reducing.
@@ -249,6 +260,9 @@ class MeshReducer(Reducer):
 
     def _sum(self, x):
         return jax.lax.psum(x, self.axis_name)
+
+    def _gather(self, x):
+        return jax.lax.all_gather(x, self.axis_name)
 
     def shard_index(self):
         return jax.lax.axis_index(self.axis_name)
@@ -278,6 +292,9 @@ class LocalReducer(Reducer):
         if compress and x.dtype != wire:
             return x.astype(wire).astype(x.dtype)
         return x
+
+    def all_gather(self, x, phase: str):
+        return x[None]
 
     def _sum(self, x):
         return x

@@ -10,6 +10,9 @@ so they are consecutive and the tile stays in VMEM across them; a step
 with no entry adds nothing.  The layout is index arithmetic and gathers
 over the [P] and [P, Pk] entries, never a scatter into [W, K].  The
 matrix itself is passed through untouched (no [W, K] copy per call).
+A topic id outside [0, K) adds nothing: the kernel's compare matches no
+column and the tail's scatter drops it.  That is how a topic shard's
+selection passes the slots other shards own (the sentinel ``K``).
 
 Rows in the matrix's last, partial 8-row tile (``row >= W8``, W8 = W
 rounded down to 8), which the kernels cannot address: the gather takes

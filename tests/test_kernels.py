@@ -137,6 +137,30 @@ def test_power_pack_shape_sweep(W, K, P, Pk, rows, guard):
         np.asarray(jax.jit(pw.scatter_add_rows)(mat, sel_w, sel_k, vals)))
 
 
+@pytest.mark.parametrize("W,K,P,Pk", [(64, 32, 12, 4), (67, 40, 20, 6),
+                                      (131, 16, 30, 16)])
+def test_power_pack_scatter_skips_sentinel_ids(W, K, P, Pk):
+    """A topic shard's selection holds the sentinel id K in the slots
+    another shard owns, and rows that own no selected topic at all (all
+    sentinel, in the body and in the partial last tile): the scatter adds
+    nothing there, as XLA's out-of-bounds drop does."""
+    rng = np.random.default_rng(W * K + P)
+    mat = jnp.asarray(rng.normal(size=(W, K)).astype(np.float32))
+    rest = np.setdiff1d(np.arange(W), [W - 1, 3])
+    # a tail row and a body row first
+    sel_w = np.r_[W - 1, 3, rng.choice(rest, P - 2, replace=False)]
+    sel_k = np.stack([rng.choice(K, Pk, replace=False) for _ in range(P)])
+    sel_k[rng.random((P, Pk)) < 0.6] = K
+    sel_k[:2] = K                           # own no selected topic
+    vals = rng.normal(size=(P, Pk)).astype(np.float32)
+    args = (jnp.asarray(sel_w.astype(np.int32)),
+            jnp.asarray(sel_k.astype(np.int32)), jnp.asarray(vals))
+    got = np.asarray(pp_ops.scatter_add_rows(mat, *args))
+    np.testing.assert_array_equal(
+        got, np.asarray(jax.jit(pw.scatter_add_rows)(mat, *args)))
+    np.testing.assert_array_equal(got[[W - 1, 3]], np.asarray(mat)[[W - 1, 3]])
+
+
 def test_power_pack_scatter_moves_tail_entries_alone():
     """At W % 8 != 0 no XLA scatter into the [W, K] matrix takes more than
     7 * Pk updates: the partial last tile's rows get only their entries."""

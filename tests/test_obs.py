@@ -165,6 +165,45 @@ def test_power_selection_owns_the_top_k(step_text):
     assert {scopes[n] for n in top_k} == {"pobp.select"}
 
 
+@pytest.fixture(scope="module")
+def topic_sharded_text():
+    """The per-shard POBP body with its topics over two shards of a
+    named ``model`` axis (vmap), compiled on the CPU."""
+    from repro.core.pobp import pobp_shard_body
+    from repro.core.sync import LocalReducer, MeshReducer
+    cfg = LDAConfig(vocab_size=256, num_topics=16, lambda_w=0.1,
+                    lambda_k_abs=4, inner_iters=3, residual_tol=0.0,
+                    impl="xla")
+
+    def shard(wid, cnt, phi_l):
+        return pobp_shard_body(wid, cnt, phi_l, jax.random.PRNGKey(0),
+                               jnp.float32(1.0), cfg, LocalReducer(),
+                               MeshReducer("model"))[:3]
+
+    sds = jax.ShapeDtypeStruct
+    return jax.jit(jax.vmap(shard, in_axes=(None, None, 0),
+                            axis_name="model")).lower(
+        sds((8, 16), jnp.int32), sds((8, 16), jnp.float32),
+        sds((2, 256, 8), jnp.float32)).compile().as_text()
+
+
+def test_topic_merge_and_model_norm_own_compiled_instructions(
+        topic_sharded_text):
+    """Topics over the model axis: the merge of power-topic candidates
+    and the selective sweep's renormalization reach the compiled
+    instructions' op names, each under its own scope."""
+    scopes = obs.op_scopes(topic_sharded_text, STEP_SCOPES
+                           + ("pobp.topic_merge", "pobp.model_norm"))
+    owned = {s for s in scopes.values() if s is not None}
+    assert {"pobp.topic_merge", "pobp.model_norm"} <= owned
+    ops = obs.hlo_ops(topic_sharded_text)
+    sorts = {scopes[n] for n, (op, name) in ops.items()
+             if op == "sort" or (op == "custom-call"
+                                 and name.endswith("top_k"))}
+    # the shard's own candidates, then the merge of every shard's
+    assert {"pobp.select", "pobp.topic_merge"} <= sorts
+
+
 @pytest.mark.parametrize("line, name, opcode", [
     ('  %fusion.202 = f32[8,128]{1,0:T(8,128)} fusion(f32[8] %p), '
      'kind=kLoop, calls=%fc, metadata={op_name="jit(step)/while/body/'

@@ -1,7 +1,8 @@
 """Every Pallas kernel of the main path, compiled by Mosaic for one
 described TPU v5e chip at NYTimes width (W=102,660, K=1000, lambda_W=0.1
 so P=10,266 power words; Pk=50), with no chip attached; ``power_pack``
-also at PubMed width (W=141,043, K=2000, P=14,104, Pk=100).
+also at PubMed width (W=141,043, K=2000, P=14,104, Pk=100) and at one
+topic shard of K=10,000 over four chips (Kl=2,500, Pk=50).
 
 A compile that passes here is not a chip run: nothing executes.  It is
 what the chip's compiler accepts — block shapes, VMEM limits, DMA
@@ -140,8 +141,9 @@ def test_nytimes_training_sweep_dispatches_to_xla():
 
 
 @pytest.mark.parametrize("w,k,p,pk", [(W, K, P, PK),
-                                      (141_043, 2000, 14_104, 100)],
-                         ids=["nytimes", "pubmed"])
+                                      (141_043, 2000, 14_104, 100),
+                                      (141_043, 2500, 14_104, 50)],
+                         ids=["nytimes", "pubmed", "pubmed-k10000-shard"])
 def test_power_pack_gather_and_scatter(one_chip, mosaic, w, k, p, pk):
     """The packed gather and the tile-visit scatter at NYTimes and PubMed
     width; the scatter's kernel keeps the op name the benchmark's
